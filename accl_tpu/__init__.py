@@ -8,9 +8,7 @@ a native C++ multi-rank emulator preserves the reference's CPU-only test
 topology. See SURVEY.md for the structural analysis of the reference.
 """
 
-from .utils import compat as _compat  # imports no jax itself
-_compat.install_if_jax_loaded()  # shims only when jax is already resident
-from .constants import (  # noqa: F401,E402
+from .constants import (  # noqa: F401
     ACCLError,
     CfgFunc,
     CompressionFlags,
@@ -26,7 +24,7 @@ from .constants import (  # noqa: F401,E402
     TuningParams,
     error_code_to_string,
 )
-from .errors import (  # noqa: F401,E402
+from .errors import (  # noqa: F401
     ACCLValidationError,
     DtypeMismatchError,
     InvalidRootError,

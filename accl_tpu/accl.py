@@ -1003,7 +1003,8 @@ class ACCL:
         by tools/timing_model.py). tier="tpu" uses the on-chip
         calibration tier instead of the emulator link fit (dispatch alpha
         + HBM-bounded beta — a projection until ICI is measured on a
-        multi-chip slice). `wire_dtype` tunes for a workload running
+        multi-chip slice); the shipped model has none until a chip run
+        refits it, and tier="tpu" then raises ValueError. `wire_dtype` tunes for a workload running
         that compression lane on its collectives (e.g. DataType.int8 for
         the blockwise-quantized wire): crossover arithmetic happens in
         wire bytes, so byte-threshold registers stretch by the

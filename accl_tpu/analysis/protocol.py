@@ -451,7 +451,7 @@ def trace_schedule_hops(options, plan, world: int,
 
 def iter_ppermute_eqns(jaxpr):
     """Yield every ppermute equation of a (closed) jaxpr, depth-first
-    through eqn-param sub-jaxprs (pjit bodies, shard_map, scan/cond
+    through eqn-param sub-jaxprs (jit bodies, shard_map, scan/cond
     branches), in trace order. THE walker for the 'every cross-rank hop
     is a ppermute' invariant — the protocol pass reads perms from it and
     bench.py's wire-byte audit sums operand bytes over it, so a jax

@@ -95,17 +95,6 @@ class UnsupportedSchedule(Exception):
 # ---------------------------------------------------------------------------
 
 
-def _literal_type():
-    try:
-        from jax.extend import core as jex_core
-
-        return jex_core.Literal
-    except (ImportError, AttributeError):  # pragma: no cover - old jax
-        from jax import core as jcore
-
-        return jcore.Literal
-
-
 @dataclasses.dataclass
 class _Sym:
     """One rank's abstract (payload-carrying) array during lifting:
@@ -141,7 +130,9 @@ class _Lifter:
         self.world = world
         self.nodes: list[Node] = []
         self.hops = 0
-        self._literal = _literal_type()
+        from jax.extend import core as jex_core
+
+        self._literal = jex_core.Literal
         # Evaluation memos, keyed by object identity and kept alive for
         # the lift's duration (holding the keyed objects in the values
         # prevents id reuse). A scan body re-evaluates its jaxpr once
@@ -219,7 +210,7 @@ class _Lifter:
             return [self._ppermute(eqn, invals[0])]
         if name == "axis_index":
             return [list(self._axis_vals)]
-        if name in ("pjit", "closed_call", "core_call"):
+        if name in ("jit", "closed_call", "core_call"):
             return self._call(eqn, invals)
         if name == "scan":
             return self._scan(eqn, invals)

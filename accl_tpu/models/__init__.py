@@ -8,11 +8,10 @@ gradient sync all run through the framework's own schedule bodies inside
 one compiled training step.
 """
 
-from ..utils import compat as _compat
-
-_compat.install()  # jax version shims, before the jax-heavy modules load
-
-from .transformer import (  # noqa: F401,E402
+from .transformer import (  # noqa: F401
+    FLAGSHIP_BATCH,
+    FLAGSHIP_CONFIG,
+    FLAGSHIP_SEQ,
     TransformerConfig,
     init_kv_cache,
     init_params,

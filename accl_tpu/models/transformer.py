@@ -61,6 +61,14 @@ class TransformerConfig:
         return kv
 
 
+# The flagship dense transformer at its on-chip width, one v5e chip at
+# batch 8 x seq 1024: bench.py's train and decode lanes and chip_smoke.py.
+FLAGSHIP_CONFIG = TransformerConfig(vocab=32768, d_model=1024, n_heads=16,
+                                    n_kv_heads=4, n_layers=8, d_ff=4096,
+                                    dtype="bfloat16")
+FLAGSHIP_BATCH, FLAGSHIP_SEQ = 8, 1024
+
+
 def init_params(cfg: TransformerConfig, key) -> dict:
     """Global (unsharded) parameter pytree; shard with shard_params."""
     keys = jax.random.split(key, 2 + cfg.n_layers)

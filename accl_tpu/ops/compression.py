@@ -35,8 +35,10 @@ Compressor lane numbering (referenced from ArithConfig rows):
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Callable, Iterator
 
+import jax
 import jax.numpy as jnp
 
 from ..arithconfig import (
@@ -55,7 +57,7 @@ from ..constants import QUANT_BLOCK_ELEMS, QUANT_INV_QMAX, QUANT_QMAX
 # provenance. Under `semantic_boundaries()` — active ONLY while the
 # certifier traces, never on a compile path — each public transform
 # routes through a named jax.jit wrapper around the SAME jnp reference
-# implementation, so the traced jaxpr carries one `pjit` equation whose
+# implementation, so the traced jaxpr carries one `jit` equation whose
 # `name` identifies the transform (accl_sem_encode / accl_sem_decode /
 # accl_sem_dequant_combine_* / accl_sem_dequant_requant_*) and the
 # certifier can apply the lane's semantic rule (codes carry their
@@ -86,13 +88,11 @@ def semantic_boundaries() -> Iterator[None]:
 
 
 def _sem_jit(name: str, fn: Callable, *statics) -> Callable:
-    """A cached jax.jit of `fn` whose pjit equation is named `name`
+    """A cached jax.jit of `fn` whose jit equation is named `name`
     (the statics distinguish closures specialized per shape/dtype)."""
     key = (name, *statics)
     jitted = _SEM_JITS.get(key)
     if jitted is None:
-        import jax
-
         fn.__name__ = name
         jitted = jax.jit(fn)
         while len(_SEM_JITS) >= _SEM_JITS_CAP:
@@ -279,8 +279,16 @@ def _dequant_combine_impl(q, scales, local, func_op: str):
     if _use_quant_pallas():
         from .pallas_kernels import fused_dequant_combine_pallas
 
-        return fused_dequant_combine_pallas(q, scales, local, op=func_op,
-                                            interpret=False)
+        return jax.lax.platform_dependent(
+            q, scales, local,
+            tpu=functools.partial(fused_dequant_combine_pallas, op=func_op,
+                                  interpret=False),
+            default=functools.partial(_dequant_combine_jnp,
+                                      func_op=func_op))
+    return _dequant_combine_jnp(q, scales, local, func_op)
+
+
+def _dequant_combine_jnp(q, scales, local, func_op: str):
     x = _dequantize_impl(q, scales, local.shape[-1], jnp.float32)
     loc = local.astype(jnp.float32)
     out = jnp.add(x, loc) if func_op == "sum" else jnp.maximum(x, loc)
@@ -300,22 +308,22 @@ def dequant_combine_requant(q, scales, local, func_op: str):
     if _use_quant_pallas():
         from .pallas_kernels import fused_dequant_combine_quant_pallas
 
-        return fused_dequant_combine_quant_pallas(q, scales, local,
-                                                  op=func_op,
-                                                  interpret=False)
+        return jax.lax.platform_dependent(
+            q, scales, local,
+            tpu=functools.partial(fused_dequant_combine_quant_pallas,
+                                  op=func_op, interpret=False),
+            default=lambda qq, ss, ll: quantize_blockwise(
+                _dequant_combine_jnp(qq, ss, ll, func_op)))
     return quantize_blockwise(dequant_combine(q, scales, local, func_op))
 
 
 def _use_quant_pallas() -> bool:
     """Route the fused quantized ring step through the Mosaic kernels:
-    on-TPU only, and opt-in (ACCL_QUANT_PALLAS=1) until the kernel tier
-    is measured on hardware — the jnp fallback is numerically identical
-    (the interpret-mode parity test pins it), so flipping the knob
-    changes the datapath, not the results."""
+    opt-in (ACCL_QUANT_PALLAS=1) until the kernel tier is measured on
+    hardware, and only in programs lowered for a TPU (the callers'
+    platform_dependent keeps the jnp form elsewhere) — the jnp form is
+    numerically identical (the interpret-mode parity test pins it), so
+    flipping the knob changes the datapath, not the results."""
     import os
 
-    if os.environ.get("ACCL_QUANT_PALLAS") != "1":
-        return False
-    from .pallas_kernels import _on_tpu
-
-    return _on_tpu()
+    return os.environ.get("ACCL_QUANT_PALLAS") == "1"

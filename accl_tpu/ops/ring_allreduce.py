@@ -14,9 +14,11 @@ allgather hops (reduced chunks relay around). Double-slotted comm buffers
 + DMA semaphores provide the rx-ring discipline the reference implements
 in rxbuf_offload.
 
-Runs under shard_map; on CPU meshes it executes in Pallas TPU interpret
-mode, which also gives schedule race detection (InterpretParams
-detect_races) — see tests/test_pallas_kernels.py.
+Runs under shard_map. Lowered for a TPU mesh it compiles to Mosaic; lowered
+for a CPU mesh it executes in Pallas TPU interpret mode, which also gives
+schedule race detection (InterpretParams detect_races) — see
+tests/test_pallas_kernels.py. The caller, who owns the mesh, picks the mode
+with `interpret_for(mesh)`.
 """
 
 from __future__ import annotations
@@ -41,9 +43,32 @@ from ..constants import ReduceFunction
 NUM_RING_SLOTS = 2
 
 
-def _slot_id(slot: int, bidir: bool) -> int:
+def mesh_on_tpu(mesh) -> bool:
+    """Whether programs over `mesh` are lowered for TPUs: the platform of
+    the mesh's devices, not the process's default backend (a CPU process
+    may lower for a described TPU topology). A device-less mesh lowers
+    for no platform."""
+    return (mesh.devices is not None
+            and mesh.devices.flat[0].platform == "tpu")
+
+
+def interpret_for(mesh, detect_races: bool = False):
+    """The ring kernels' `interpret` for a program lowered over `mesh`:
+    Mosaic (False) when the mesh's devices are TPUs, the TPU interpreter
+    (optionally race-detecting) otherwise."""
+    if mesh_on_tpu(mesh):
+        return False
+    return pltpu.InterpretParams(detect_races=detect_races)
+
+
+def _slot_id(slot: int, bidir: bool, world: int) -> int | None:
+    """The slot's collective_id, or None at world 1: the kernel then
+    takes no barrier semaphore, and Mosaic refuses a collective_id
+    without one."""
     if not 0 <= slot < NUM_RING_SLOTS:
         raise ValueError(f"ring slot {slot} outside 0..{NUM_RING_SLOTS - 1}")
+    if world == 1:
+        return None
     return 2 * slot + (1 if bidir else 0)
 
 
@@ -135,10 +160,9 @@ def _compiled_f16_detour(x, interpret):
     least as accurate (fp32 ring accumulation, one final f16 round) at the
     cost of 2x wire bytes. Interpret-mode (CPU) f16 stays on the native
     f16 path. Returns a rerun closure, or None when no detour is needed."""
-    from .pallas_kernels import _mosaic_rejects, _on_tpu
+    from .pallas_kernels import _mosaic_rejects
 
-    compiled = (interpret is False) or (interpret is None and _on_tpu())
-    if not (compiled and _mosaic_rejects(x.dtype)):
+    if not (interpret is False and _mosaic_rejects(x.dtype)):
         return None
     orig = x.dtype
 
@@ -153,9 +177,8 @@ def ring_allreduce_pallas(
     *,
     axis_name: str,
     world: int,
+    interpret,
     func: ReduceFunction = ReduceFunction.SUM,
-    interpret=None,
-    detect_races: bool = False,
     slot: int = 0,
 ):
     """Per-device body (call inside shard_map): fused ring allreduce of a
@@ -166,8 +189,7 @@ def ring_allreduce_pallas(
     if f16_detour is not None:
         return f16_detour(
             ring_allreduce_pallas, axis_name=axis_name, world=world,
-            func=func, interpret=interpret, detect_races=detect_races,
-            slot=slot)
+            func=func, interpret=interpret, slot=slot)
     n = x.shape[-1]
     tile = _sublane(x.dtype) * 128
     chunk = -(-n // world)
@@ -177,13 +199,6 @@ def ring_allreduce_pallas(
         x = jnp.pad(x, (0, padded - n))
     x2 = x.reshape(padded // 128, 128)
     chunk_rows = chunk // 128
-
-    if interpret is None:
-        from .pallas_kernels import _on_tpu
-
-        interpret = (
-            False if _on_tpu() else pltpu.InterpretParams(detect_races=detect_races)
-        )
 
     kernel = functools.partial(_kernel, axis_name, world, chunk_rows, func)
     out = pl.pallas_call(
@@ -201,7 +216,7 @@ def ring_allreduce_pallas(
             pltpu.SemaphoreType.REGULAR((2,)),  # slot release credits
         ],
         compiler_params=pltpu.CompilerParams(
-            collective_id=_slot_id(slot, bidir=False)),
+            collective_id=_slot_id(slot, bidir=False, world=world)),
         interpret=interpret,
     )(x2)
     return out.reshape(padded)[:n]
@@ -305,9 +320,8 @@ def ring_allreduce_pallas_bidir(
     *,
     axis_name: str,
     world: int,
+    interpret,
     func: ReduceFunction = ReduceFunction.SUM,
-    interpret=None,
-    detect_races: bool = False,
     slot: int = 0,
 ):
     """Bidirectional fused ring allreduce of a flat (n,) buffer. `slot`
@@ -317,8 +331,7 @@ def ring_allreduce_pallas_bidir(
     if f16_detour is not None:
         return f16_detour(
             ring_allreduce_pallas_bidir, axis_name=axis_name, world=world,
-            func=func, interpret=interpret, detect_races=detect_races,
-            slot=slot)
+            func=func, interpret=interpret, slot=slot)
     n = x.shape[-1]
     # pad so n splits into 2 * world whole-tile chunks
     tile = _sublane(x.dtype) * 128
@@ -329,13 +342,6 @@ def ring_allreduce_pallas_bidir(
         x = jnp.pad(x, (0, padded - n))
     x2 = x.reshape(padded // 128, 128)
     chunk_rows = chunk // 128
-
-    if interpret is None:
-        from .pallas_kernels import _on_tpu
-
-        interpret = (
-            False if _on_tpu() else pltpu.InterpretParams(detect_races=detect_races)
-        )
 
     kernel = functools.partial(_kernel_bidir, axis_name, world, chunk_rows, func)
     out = pl.pallas_call(
@@ -357,7 +363,7 @@ def ring_allreduce_pallas_bidir(
             pltpu.SemaphoreType.REGULAR((2,)),
         ],
         compiler_params=pltpu.CompilerParams(
-            collective_id=_slot_id(slot, bidir=True)),
+            collective_id=_slot_id(slot, bidir=True, world=world)),
         interpret=interpret,
     )(x2)
     return out.reshape(padded)[:n]

@@ -10,11 +10,7 @@ style all-to-all sequence parallelism, both composable inside shard_map
 alongside the sequencer's collective schedule bodies.
 """
 
-from ..utils import compat as _compat
-
-_compat.install()  # jax version shims, before the jax-heavy modules load
-
-from .mesh import factorize_devices, make_mesh  # noqa: F401,E402
-from .pipeline import gpipe_schedule  # noqa: F401,E402
-from .ring_attention import ring_attention  # noqa: F401,E402
+from .mesh import factorize_devices, make_mesh  # noqa: F401
+from .pipeline import gpipe_schedule  # noqa: F401
+from .ring_attention import ring_attention  # noqa: F401
 from .ulysses import ulysses_attention  # noqa: F401

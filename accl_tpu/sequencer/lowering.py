@@ -18,11 +18,7 @@ from __future__ import annotations
 import functools
 from typing import Callable
 
-from ..utils import compat as _compat
-
-_compat.install()  # jax version shims, before any jax.shard_map use
-
-import jax  # noqa: E402
+import jax
 from jax.sharding import Mesh, PartitionSpec
 
 from ..arithconfig import DEFAULT_ARITH_CONFIG, ArithConfig
@@ -60,12 +56,14 @@ class ScheduleCompiler:
         self.mesh = mesh
         self.axis_name = axis_name
         self.arith_table = arith_table or DEFAULT_ARITH_CONFIG
-        if use_pallas_ring is None:
-            # Auto: the fused ICI kernel on real TPU, lax schedules on the
-            # CPU emulation mesh (where interpret-mode kernels are slower).
-            from ..ops.pallas_kernels import _on_tpu
+        from ..ops.ring_allreduce import mesh_on_tpu
 
-            use_pallas_ring = _on_tpu()
+        self.on_tpu = mesh_on_tpu(mesh)
+        if use_pallas_ring is None:
+            # Auto: the fused ICI kernel on a TPU mesh, lax schedules on
+            # the CPU emulation mesh (where interpret-mode kernels are
+            # slower).
+            use_pallas_ring = self.on_tpu
         self.use_pallas_ring = use_pallas_ring
         if pallas_ring_overlap is None:
             # segment-slot double-buffering for the large-payload pallas
@@ -393,7 +391,7 @@ class ScheduleCompiler:
                 # requested wire compression keeps its bandwidth meaning
                 # (the kernel-level _compiled_f16_detour would silently
                 # widen the wire back to fp32).
-                from ..ops.pallas_kernels import _mosaic_rejects, _on_tpu
+                from ..ops.pallas_kernels import _mosaic_rejects
 
                 from ..constants import to_numpy_dtype
 
@@ -405,7 +403,7 @@ class ScheduleCompiler:
                 mosaic_ok = not (
                     ring_dtype is not None
                     and _mosaic_rejects(ring_dtype)
-                    and _on_tpu()
+                    and self.on_tpu
                 )
                 if (
                     self.use_pallas_ring
@@ -425,6 +423,7 @@ class ScheduleCompiler:
                 ):
                     from ..ops.ring_allreduce import (
                         NUM_RING_SLOTS,
+                        interpret_for,
                         ring_allreduce_pallas_bidir,
                     )
 
@@ -443,10 +442,11 @@ class ScheduleCompiler:
                     # governs the lax path.)
                     seg_elems = max(self.PALLAS_RING_MAX_BYTES // elem_bytes, 1)
 
-                    def one_seg(y, slot=0, *, _c=common, _f=func):
+                    def one_seg(y, slot=0, *, _c=common, _f=func,
+                                _i=interpret_for(self.mesh)):
                         return ring_allreduce_pallas_bidir(
                             y, axis_name=_c["axis"], world=_c["world"],
-                            func=_f, slot=slot,
+                            func=_f, slot=slot, interpret=_i,
                         )
 
                     def _pallas_ring_body(x, *, _c=common, _seg=seg_elems,
@@ -567,6 +567,8 @@ class AxisOnlyMesh:
     """The minimal mesh surface `ScheduleCompiler._body` consumes (axis
     size lookup); tracing under make_jaxpr's axis env needs no
     devices."""
+
+    devices = None  # lowers for no platform
 
     def __init__(self, axis_name: str, world: int):
         self.shape = {axis_name: world}

@@ -19,28 +19,9 @@ from accl_tpu.ops.pallas_kernels import (
     combine_pallas,
     fused_combine_cast_pallas,
 )
-from accl_tpu.ops.ring_allreduce import ring_allreduce_pallas
+from accl_tpu.ops.ring_allreduce import interpret_for, ring_allreduce_pallas
 
 RNG = np.random.default_rng(3)
-
-# Platform gap, keyed so regressions are distinguishable from environment:
-# off-TPU the ring kernels run in Pallas TPU interpret mode, which needs
-# `pltpu.InterpretParams` (ring_allreduce.py builds it per launch for
-# race detection). jax 0.4.x ships no InterpretParams, so the interpret
-# path cannot even construct its parameters there. On a real TPU the
-# kernels compile through Mosaic and none of this applies.
-from jax.experimental.pallas import tpu as _pltpu  # noqa: E402
-
-from accl_tpu.ops.pallas_kernels import _on_tpu  # noqa: E402
-
-ring_interpret_gap = pytest.mark.skipif(
-    not _on_tpu() and not hasattr(_pltpu, "InterpretParams"),
-    reason="platform gap: jax.experimental.pallas.tpu.InterpretParams "
-           "absent (jax " + jax.__version__ + "); the CPU interpret path "
-           "for the fused ring kernels needs it — run on real TPU or "
-           "jax >= 0.6 to exercise these",
-)
-
 
 @pytest.mark.parametrize("n", [128, 1000, 65536, 65537])
 @pytest.mark.parametrize("op", ["sum", "max"])
@@ -74,14 +55,13 @@ def test_fused_combine_cast():
                                rtol=1e-2, atol=1e-2)
 
 
-@ring_interpret_gap
 @pytest.mark.parametrize("world,n", [(4, 1024), (8, 2048), (8, 1000), (2, 256)])
 def test_ring_allreduce_kernel(world, n):
     devs = np.array(jax.devices()[:world])
     mesh = Mesh(devs, ("ccl",))
     body = functools.partial(
         ring_allreduce_pallas, axis_name="ccl", world=world,
-        func=ReduceFunction.SUM,
+        func=ReduceFunction.SUM, interpret=interpret_for(mesh),
     )
     fn = jax.jit(
         jax.shard_map(
@@ -98,7 +78,6 @@ def test_ring_allreduce_kernel(world, n):
                                rtol=1e-4, atol=1e-4)
 
 
-@ring_interpret_gap
 def test_ring_allreduce_race_detector():
     """Run the fused kernel under the TPU interpreter's race detector —
     the framework's schedule race-checking facility."""
@@ -107,7 +86,8 @@ def test_ring_allreduce_race_detector():
     mesh = Mesh(devs, ("ccl",))
     body = functools.partial(
         ring_allreduce_pallas, axis_name="ccl", world=world,
-        func=ReduceFunction.SUM, detect_races=True,
+        func=ReduceFunction.SUM,
+        interpret=interpret_for(mesh, detect_races=True),
     )
     fn = jax.jit(
         jax.shard_map(
@@ -124,7 +104,6 @@ def test_ring_allreduce_race_detector():
                                rtol=1e-4, atol=1e-4)
 
 
-@ring_interpret_gap
 def test_pallas_ring_through_facade(mesh8):
     """Full driver path with the fused kernel enabled (the TPU default)."""
     from accl_tpu.accl import ACCL
@@ -141,7 +120,6 @@ def test_pallas_ring_through_facade(mesh8):
                                rtol=1e-4, atol=1e-4)
 
 
-@ring_interpret_gap
 @pytest.mark.parametrize("world,n", [(4, 2048), (8, 4000), (2, 512)])
 def test_bidirectional_ring_allreduce(world, n):
     from accl_tpu.ops.ring_allreduce import ring_allreduce_pallas_bidir
@@ -150,7 +128,8 @@ def test_bidirectional_ring_allreduce(world, n):
     mesh = Mesh(devs, ("ccl",))
     body = functools.partial(
         ring_allreduce_pallas_bidir, axis_name="ccl", world=world,
-        func=ReduceFunction.SUM, detect_races=(world == 4),
+        func=ReduceFunction.SUM,
+        interpret=interpret_for(mesh, detect_races=(world == 4)),
     )
     fn = jax.jit(
         jax.shard_map(
@@ -167,7 +146,6 @@ def test_bidirectional_ring_allreduce(world, n):
                                rtol=1e-4, atol=1e-4)
 
 
-@ring_interpret_gap
 def test_pallas_ring_segmented_large_payload(mesh8):
     """Payloads past the VMEM ceiling run the fused kernel per segment."""
     from accl_tpu.accl import ACCL

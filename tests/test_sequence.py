@@ -268,14 +268,6 @@ def test_segmented_apply_overlap_slots_correct():
     assert calls == [(23, 0)]  # single segment: slot 0, no pipeline
 
 
-def _interpret_mode_available():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return hasattr(pltpu, "InterpretParams")
-
-
-@pytest.mark.skipif(not _interpret_mode_available(),
-                    reason="pallas InterpretParams unavailable on this jax")
 def test_pallas_ring_overlap_matches_serialized(mesh4):
     """The slot-overlapped segmented pallas ring must agree with the
     serialized baseline (and the oracle) when the payload spans several

@@ -676,17 +676,11 @@ def test_facade_autotune_sets_hier_register_and_tier_wires(mesh8):
 def _traced_ppermute_bytes(opts, plan, world):
     """Per-rank ppermute operand bytes of the REAL lowered program —
     the executable truth the cost shape must match."""
-    import jax
+    from jax.extend import core as jcore
 
     from accl_tpu.analysis.protocol import (iter_ppermute_eqns,
                                             trace_schedule_jaxpr)
 
-    try:
-        from jax.extend import core as jcore
-    except ImportError:  # pragma: no cover - old jax
-        import jax.core as jcore
-
-    del jax
     closed, _, _ = trace_schedule_jaxpr(opts, plan, world)
     return sum(v.aval.size * v.aval.dtype.itemsize
                for eqn in iter_ppermute_eqns(closed)
