@@ -293,20 +293,30 @@ class ACCL:
             compress_dtype=compress_dtype or DataType.none,
         )
 
-    def _stage_in(self, sync_in: list[BaseBuffer], from_device: bool):
+    def _stage_in(self, sync_in: list[BaseBuffer], from_device: bool,
+                  call=None):
         """Pre-launch host->HBM staging: host-only operands always stage;
         device buffers only when the caller didn't claim from_device
-        residence."""
-        for b in sync_in:
-            if not from_device or getattr(b, "host_only", False):
-                b.sync_to_device()
+        residence. `call`: the collected call span, which then gets a
+        `stage_in` child when something stages."""
+        staged = [b for b in sync_in
+                  if not from_device or getattr(b, "host_only", False)]
+        if not staged:
+            return
+        sp = (call.begin("stage_in", bytes=sum(b.nbytes for b in staged))
+              if call else None)
+        for b in staged:
+            b.sync_to_device()
+        if sp:
+            sp.end()
 
     def _complete(self, req, sync_out: list[BaseBuffer], to_device: bool,
-                  run_async: bool):
+                  run_async: bool, call=None):
         """Post-launch completion contract shared by single calls and
         recorded sequences: async defers sync-out to wait() (host-only
         results still need their copy-back even under to_device), sync
-        waits/checks and pulls results."""
+        waits/checks and pulls results (a `stage_out` child of the
+        collected call span `call`, when something stages)."""
         self._last_request = req
         if run_async:
             if to_device:
@@ -318,13 +328,21 @@ class ACCL:
             return req
         req.wait()
         req.check()
-        for b in sync_out:
-            if not to_device or getattr(b, "host_only", False):
+        staged = [b for b in sync_out
+                  if not to_device or getattr(b, "host_only", False)]
+        if staged:
+            sp = (call.begin("stage_out",
+                             bytes=sum(b.nbytes for b in staged))
+                  if call else None)
+            for b in staged:
                 b.sync_from_device()
+            if sp:
+                sp.end()
         return req
 
     def _execute(
         self,
+        sp,
         opts: CallOptions,
         sync_in: list[BaseBuffer],
         sync_out: list[BaseBuffer],
@@ -332,6 +350,11 @@ class ACCL:
         to_device: bool,
         run_async: bool,
     ):
+        """Stage, start, complete one prepared call inside its call
+        span `sp`, which the public method opened at its entry
+        (get_tracer().call: the shared no-op when telemetry is off,
+        one predicate; the bench smoke path gates the disabled cost
+        <1%)."""
         # armed deadlines (resilience seam): time the synchronous call
         # end to end so the manager can check it against its
         # model-derived deadline after completion. async calls complete
@@ -339,34 +362,33 @@ class ACCL:
         mgr = self._resilience
         t0 = (time.perf_counter()
               if mgr is not None and not run_async else None)
-        # tracer.span is the shared no-op when telemetry is off (one
-        # predicate; the bench smoke path gates the disabled cost <1%)
-        with get_tracer().span(opts.scenario.name, cat="call",
-                               track="facade") as sp:
-            self._stage_in(sync_in, from_device)
-            Log.debug("call %s count=%d flags=c%x/s%x", opts.scenario.name,
-                      opts.count, int(opts.compression_flags),
-                      int(opts.stream_flags))
-            req = self.cclo.start(opts)
-            ret = self._complete(req, sync_out, to_device, run_async)
-            if mgr is not None and t0 is not None:
-                mgr.observe_call(opts.scenario, opts.count,
-                                 dtype_nbytes(opts.data_type)
-                                 if opts.data_type != DataType.none else 4,
-                                 time.perf_counter() - t0)
-            if get_tracer().active:  # attach what the device resolved
-                sp.set(op=opts.scenario.name, count=opts.count,
-                       retcode=req.retcode)
-                if run_async:
-                    sp.set(dispatch_only=True)
-                plan = getattr(req, "plan", None)
-                if plan is not None:
-                    sp.set(algorithm=plan.algorithm.name,
-                           protocol=plan.protocol.name)
-                pred = getattr(req, "predicted_s", None)
-                if pred is not None:
-                    sp.set(predicted_s=pred)
-            return ret
+        # children (stage_in/out here, plan/lower/launch/wait/place in
+        # the device) only while spans are collected
+        call = sp if sp.keep else None
+        self._stage_in(sync_in, from_device, call)
+        Log.debug("call %s count=%d flags=c%x/s%x", opts.scenario.name,
+                  opts.count, int(opts.compression_flags),
+                  int(opts.stream_flags))
+        req = self.cclo.start(opts)
+        ret = self._complete(req, sync_out, to_device, run_async, call)
+        if mgr is not None and t0 is not None:
+            mgr.observe_call(opts.scenario, opts.count,
+                             dtype_nbytes(opts.data_type)
+                             if opts.data_type != DataType.none else 4,
+                             time.perf_counter() - t0)
+        if sp:  # a live span: attach what the device resolved
+            sp.set(op=opts.scenario.name, count=opts.count,
+                   retcode=req.retcode)
+            if run_async:
+                sp.set(dispatch_only=True)
+            plan = getattr(req, "plan", None)
+            if plan is not None:
+                sp.set(algorithm=plan.algorithm.name,
+                       protocol=plan.protocol.name)
+            pred = getattr(req, "predicted_s", None)
+            if pred is not None:
+                sp.set(predicted_s=pred)
+        return ret
 
     def wait(self, req: BaseRequest):
         """Complete an async request (sync-out deferred at start time)."""
@@ -397,11 +419,15 @@ class ACCL:
     def nop(self):
         return self.cclo.call(CallOptions(scenario=Operation.nop))
 
+    # Every data-plane method opens its call span at entry, so building
+    # the descriptor (_prepare, _live_subset, _stream_opts) falls inside.
+
     def copy(self, srcbuf, dstbuf, count, *, from_device=False, to_device=False,
              run_async=False):
-        opts = self._prepare(Operation.copy, srcbuf, None, dstbuf, count)
-        return self._execute(opts, [srcbuf], [dstbuf], from_device, to_device,
-                             run_async)
+        with get_tracer().call("copy") as sp:
+            opts = self._prepare(Operation.copy, srcbuf, None, dstbuf, count)
+            return self._execute(sp, opts, [srcbuf], [dstbuf], from_device,
+                                 to_device, run_async)
 
     def _scratch(self, count, dtype, fresh=False):
         """Internal placeholder buffer for a buffer-less stream endpoint
@@ -424,10 +450,11 @@ class ACCL:
                          run_async=False):
         """Operand arrives from a registered producer stream, result lands
         in dstbuf (reference copy_from_stream, accl.hpp:317)."""
-        opts = self._prepare(Operation.copy, dstbuf, None, dstbuf, count)
-        self._stream_opts(opts, op0_stream, None)
-        return self._execute(opts, [dstbuf], [dstbuf], True, to_device,
-                             run_async)
+        with get_tracer().call("copy") as sp:
+            opts = self._prepare(Operation.copy, dstbuf, None, dstbuf, count)
+            self._stream_opts(opts, op0_stream, None)
+            return self._execute(sp, opts, [dstbuf], [dstbuf], True,
+                                 to_device, run_async)
 
     def copy_to_stream(self, srcbuf, count, *, res_stream, dstbuf=None,
                        from_device=False, to_device=False,
@@ -440,40 +467,43 @@ class ACCL:
         device->host result sync even with a dstbuf — the chained
         on-device form (the eager train-step twin keeps its gradient
         intermediate resident between stages)."""
-        fresh = dstbuf is None and run_async
-        dst = dstbuf if dstbuf is not None else self._scratch(
-            count, srcbuf.np_dtype, fresh=run_async)
-        opts = self._prepare(Operation.copy, srcbuf, None, dst, count)
-        self._stream_opts(opts, None, res_stream)
-        # to_device=True (skip the device->host result sync) for the
-        # unobserved internal placeholder, or on caller request
-        req = self._execute(opts, [srcbuf], [dst], from_device,
-                            to_device or dstbuf is None, run_async)
-        if fresh:
-            req._accl_scratch = dst
-        return req
+        with get_tracer().call("copy") as sp:
+            fresh = dstbuf is None and run_async
+            dst = dstbuf if dstbuf is not None else self._scratch(
+                count, srcbuf.np_dtype, fresh=run_async)
+            opts = self._prepare(Operation.copy, srcbuf, None, dst, count)
+            self._stream_opts(opts, None, res_stream)
+            # to_device=True (skip the device->host result sync) for the
+            # unobserved internal placeholder, or on caller request
+            req = self._execute(sp, opts, [srcbuf], [dst], from_device,
+                                to_device or dstbuf is None, run_async)
+            if fresh:
+                req._accl_scratch = dst
+            return req
 
     def copy_from_to_stream(self, data_type, count, *, op0_stream, res_stream,
                             dstbuf=None, run_async=False):
         """Producer stream -> consumer stream, no host buffers (reference
         copy_from_to_stream, accl.hpp:349); dstbuf optionally captures the
         consumer output."""
-        scratch = self._scratch(count, data_type, fresh=run_async)
-        dst = dstbuf if dstbuf is not None else scratch
-        opts = self._prepare(Operation.copy, scratch, None, dst, count)
-        self._stream_opts(opts, op0_stream, res_stream)
-        req = self._execute(opts, [scratch], [dst], True,
-                            dstbuf is None, run_async)
-        if run_async:
-            req._accl_scratch = scratch
-        return req
+        with get_tracer().call("copy") as sp:
+            scratch = self._scratch(count, data_type, fresh=run_async)
+            dst = dstbuf if dstbuf is not None else scratch
+            opts = self._prepare(Operation.copy, scratch, None, dst, count)
+            self._stream_opts(opts, op0_stream, res_stream)
+            req = self._execute(sp, opts, [scratch], [dst], True,
+                                dstbuf is None, run_async)
+            if run_async:
+                req._accl_scratch = scratch
+            return req
 
     def combine(self, count, function, op0, op1, res, *, from_device=False,
                 to_device=False, run_async=False):
-        opts = self._prepare(Operation.combine, op0, op1, res, count,
-                             function=int(function))
-        return self._execute(opts, [op0, op1], [res], from_device, to_device,
-                             run_async)
+        with get_tracer().call("combine") as sp:
+            opts = self._prepare(Operation.combine, op0, op1, res, count,
+                                 function=int(function))
+            return self._execute(sp, opts, [op0, op1], [res], from_device,
+                                 to_device, run_async)
 
     def send(self, srcbuf, count, src, dst, tag=TAG_ANY, *, from_device=False,
              run_async=False, compress_dtype=None, comm=None,
@@ -481,21 +511,23 @@ class ACCL:
         """srcbuf may be a DataType when op0_stream is set (the reference's
         stream-send overload, accl.hpp:190: the payload comes from the
         producer kernel, not a buffer)."""
-        fresh = False
-        if isinstance(srcbuf, DataType):
-            if op0_stream is None:
-                raise ValueError("dataType-only send requires op0_stream")
-            srcbuf = self._scratch(count, srcbuf, fresh=run_async)
-            from_device = True
-            fresh = run_async
-        opts = self._prepare(Operation.send, srcbuf, None, None, count,
-                             root_src_dst=src | (dst << 16), tag=tag,
-                             compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, op0_stream, None)
-        req = self._execute(opts, [srcbuf], [], from_device, True, run_async)
-        if fresh:
-            req._accl_scratch = srcbuf
-        return req
+        with get_tracer().call("send") as sp:
+            fresh = False
+            if isinstance(srcbuf, DataType):
+                if op0_stream is None:
+                    raise ValueError("dataType-only send requires op0_stream")
+                srcbuf = self._scratch(count, srcbuf, fresh=run_async)
+                from_device = True
+                fresh = run_async
+            opts = self._prepare(Operation.send, srcbuf, None, None, count,
+                                 root_src_dst=src | (dst << 16), tag=tag,
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, None)
+            req = self._execute(sp, opts, [srcbuf], [], from_device, True,
+                                run_async)
+            if fresh:
+                req._accl_scratch = srcbuf
+            return req
 
     def recv(self, dstbuf, count, src, dst, tag=TAG_ANY, *, to_device=False,
              run_async=False, compress_dtype=None, comm=None,
@@ -503,21 +535,23 @@ class ACCL:
         """dstbuf may be a DataType when res_stream is set (the reference's
         stream-recv overload, accl.hpp:278: the payload feeds the consumer
         kernel; pass a real buffer to also capture the consumer output)."""
-        fresh = False
-        if isinstance(dstbuf, DataType):
-            if res_stream is None:
-                raise ValueError("dataType-only recv requires res_stream")
-            dstbuf = self._scratch(count, dstbuf, fresh=run_async)
-            to_device = True  # nothing observes the placeholder: skip sync
-            fresh = run_async
-        opts = self._prepare(Operation.recv, None, None, dstbuf, count,
-                             root_src_dst=src | (dst << 16), tag=tag,
-                             compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, None, res_stream)
-        req = self._execute(opts, [], [dstbuf], True, to_device, run_async)
-        if fresh:
-            req._accl_scratch = dstbuf
-        return req
+        with get_tracer().call("recv") as sp:
+            fresh = False
+            if isinstance(dstbuf, DataType):
+                if res_stream is None:
+                    raise ValueError("dataType-only recv requires res_stream")
+                dstbuf = self._scratch(count, dstbuf, fresh=run_async)
+                to_device = True  # nothing observes the placeholder
+                fresh = run_async
+            opts = self._prepare(Operation.recv, None, None, dstbuf, count,
+                                 root_src_dst=src | (dst << 16), tag=tag,
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, None, res_stream)
+            req = self._execute(sp, opts, [], [dstbuf], True, to_device,
+                                run_async)
+            if fresh:
+                req._accl_scratch = dstbuf
+            return req
 
     def _stream_opts(self, opts, op0_stream, res_stream):
         """Arm OP0_STREAM/RES_STREAM on a prepared descriptor (reference:
@@ -545,52 +579,59 @@ class ACCL:
     def bcast(self, buf, count, root, *, from_device=False, to_device=False,
               run_async=False, compress_dtype=None, comm=None,
               op0_stream=None, res_stream=None):
-        opts = self._prepare(Operation.bcast, buf, None, buf, count,
-                             root_src_dst=root, compress_dtype=compress_dtype,
-                             comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [buf], [buf], from_device, to_device,
-                             run_async)
+        with get_tracer().call("bcast") as sp:
+            opts = self._prepare(Operation.bcast, buf, None, buf, count,
+                                 root_src_dst=root,
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [buf], [buf], from_device,
+                                 to_device, run_async)
 
     def scatter(self, sendbuf, recvbuf, count, root, *, from_device=False,
                 to_device=False, run_async=False, compress_dtype=None,
                 comm=None, op0_stream=None, res_stream=None):
-        opts = self._prepare(Operation.scatter, sendbuf, None, recvbuf, count,
-                             root_src_dst=root, compress_dtype=compress_dtype,
-                             comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("scatter") as sp:
+            opts = self._prepare(Operation.scatter, sendbuf, None, recvbuf,
+                                 count, root_src_dst=root,
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def gather(self, sendbuf, recvbuf, count, root, *, from_device=False,
                to_device=False, run_async=False, compress_dtype=None,
                comm=None, op0_stream=None, res_stream=None):
-        opts = self._prepare(Operation.gather, sendbuf, None, recvbuf, count,
-                             root_src_dst=root, compress_dtype=compress_dtype,
-                             comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("gather") as sp:
+            opts = self._prepare(Operation.gather, sendbuf, None, recvbuf,
+                                 count, root_src_dst=root,
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def allgather(self, sendbuf, recvbuf, count, *, from_device=False,
                   to_device=False, run_async=False, compress_dtype=None,
                   comm=None, op0_stream=None, res_stream=None):
-        opts = self._prepare(Operation.allgather, sendbuf, None, recvbuf,
-                             count, compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("allgather") as sp:
+            opts = self._prepare(Operation.allgather, sendbuf, None, recvbuf,
+                                 count, compress_dtype=compress_dtype,
+                                 comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def reduce(self, sendbuf, recvbuf, count, root, function, *,
                from_device=False, to_device=False, run_async=False,
                compress_dtype=None, comm=None, op0_stream=None,
                res_stream=None):
-        opts = self._prepare(Operation.reduce, sendbuf, None, recvbuf, count,
-                             root_src_dst=root, function=int(function),
-                             compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("reduce") as sp:
+            opts = self._prepare(Operation.reduce, sendbuf, None, recvbuf,
+                                 count, root_src_dst=root,
+                                 function=int(function),
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def allreduce(self, sendbuf, recvbuf, count, function, *,
                   from_device=False, to_device=False, run_async=False,
@@ -607,14 +648,15 @@ class ACCL:
         ghost contribution). SUM only, exact wire only. A full
         survivor set normalizes to the ordinary allreduce bit-for-bit
         (one compiled program, like the all-full alltoallv vector)."""
-        opts = self._prepare(Operation.allreduce, sendbuf, None, recvbuf,
-                             count, function=int(function),
-                             compress_dtype=compress_dtype, comm=comm)
-        opts.live_ranks = self._live_subset(mode, live_ranks, function,
-                                            compress_dtype, comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("allreduce") as sp:
+            opts = self._prepare(Operation.allreduce, sendbuf, None, recvbuf,
+                                 count, function=int(function),
+                                 compress_dtype=compress_dtype, comm=comm)
+            opts.live_ranks = self._live_subset(mode, live_ranks, function,
+                                                compress_dtype, comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def _live_subset(self, mode, live_ranks, function, compress_dtype,
                      comm) -> tuple:
@@ -655,21 +697,24 @@ class ACCL:
                        from_device=False, to_device=False, run_async=False,
                        compress_dtype=None, comm=None, op0_stream=None,
                        res_stream=None):
-        opts = self._prepare(Operation.reduce_scatter, sendbuf, None, recvbuf,
-                             count, function=int(function),
-                             compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("reduce_scatter") as sp:
+            opts = self._prepare(Operation.reduce_scatter, sendbuf, None,
+                                 recvbuf, count, function=int(function),
+                                 compress_dtype=compress_dtype, comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def alltoall(self, sendbuf, recvbuf, count, *, from_device=False,
                  to_device=False, run_async=False, compress_dtype=None,
                  comm=None, op0_stream=None, res_stream=None):
-        opts = self._prepare(Operation.alltoall, sendbuf, None, recvbuf,
-                             count, compress_dtype=compress_dtype, comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        with get_tracer().call("alltoall") as sp:
+            opts = self._prepare(Operation.alltoall, sendbuf, None, recvbuf,
+                                 count, compress_dtype=compress_dtype,
+                                 comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def alltoallv(self, sendbuf, recvbuf, count, send_counts, *,
                   from_device=False, to_device=False, run_async=False,
@@ -685,12 +730,15 @@ class ACCL:
         fewer bytes than the dense one). An all-`count` vector is the
         dense alltoall, bit-for-bit. XLA-schedule-tier only: executors
         without the capacity-masked rotation reject up front."""
-        opts = self._prepare_alltoallv(sendbuf, recvbuf, count, send_counts,
-                                       compress_dtype=compress_dtype,
-                                       comm=comm)
-        self._stream_opts(opts, op0_stream, res_stream)
-        return self._execute(opts, [sendbuf], [recvbuf], from_device,
-                             to_device, run_async)
+        # named by the descriptor's op, which alltoallv shares
+        with get_tracer().call("alltoall") as sp:
+            opts = self._prepare_alltoallv(sendbuf, recvbuf, count,
+                                           send_counts,
+                                           compress_dtype=compress_dtype,
+                                           comm=comm)
+            self._stream_opts(opts, op0_stream, res_stream)
+            return self._execute(sp, opts, [sendbuf], [recvbuf], from_device,
+                                 to_device, run_async)
 
     def _prepare_alltoallv(self, sendbuf, recvbuf, count, send_counts, *,
                            compress_dtype=None, comm=None) -> CallOptions:
@@ -1310,15 +1358,15 @@ class SequenceRecorder:
         self._ran = True
         accl = self._accl
         sync_in, sync_out = self._sync_sets()
-        with get_tracer().span("sequence", cat="sequence",
-                               track="facade") as sp:
-            accl._stage_in(sync_in, from_device)
+        with get_tracer().call("sequence", cat="sequence") as sp:
+            call = sp if sp.keep else None
+            accl._stage_in(sync_in, from_device, call)
             Log.debug("sequence of %d: %s", len(self.calls),
                       "+".join(o.scenario.name for o in self.calls))
             req = accl.cclo.start_sequence(self.calls, lint=self._lint,
                                            persistent=self._persistent)
-            ret = accl._complete(req, sync_out, to_device, run_async)
-            if get_tracer().active:
+            ret = accl._complete(req, sync_out, to_device, run_async, call)
+            if sp:
                 sp.set(n_steps=len(self.calls),
                        ops="+".join(o.scenario.name for o in self.calls))
                 if run_async:
@@ -1383,12 +1431,13 @@ class SequenceProgram:
         """Dispatch the compiled batch over the bound buffers' current
         contents; same sync semantics as SequenceRecorder.run()."""
         accl = self._accl
-        with get_tracer().span("sequence", cat="sequence",
-                               track="facade") as sp:
-            accl._stage_in(self._sync_in, from_device)
+        with get_tracer().call("sequence", cat="sequence") as sp:
+            call = sp if sp.keep else None
+            accl._stage_in(self._sync_in, from_device, call)
             req = accl.cclo.dispatch_sequence(self._prepared)
-            ret = accl._complete(req, self._sync_out, to_device, run_async)
-            if get_tracer().active:
+            ret = accl._complete(req, self._sync_out, to_device, run_async,
+                                 call)
+            if sp:
                 sp.set(n_steps=self.n_steps, ops=self._ops, prepared=True)
                 if run_async:
                     sp.set(dispatch_only=True)

@@ -128,6 +128,10 @@ class TPUDevice(CCLODevice):
         self._comm_cache: dict[int, "_CommCtx"] = {}
         self._comm_extents: dict[int, int] = {}  # comm_addr -> table end
         self._group_cache: dict[tuple, "_CommCtx"] = {}  # members -> ctx
+        # per-program timing.predict estimates with the link they used
+        self._predictions: dict = {}
+        # results written into a wider buffer: each runs its own program
+        self.place_copies = 0
 
     # -- registry ---------------------------------------------------------
 
@@ -413,16 +417,28 @@ class TPUDevice(CCLODevice):
         return plan, producer, consumer
 
     def _launch(self, options: CallOptions) -> BaseRequest:
+        # the facade's call span while spans are collected, else None:
+        # each child span below then costs one test
+        tracer = get_tracer()
+        call = tracer.current()
         ctx = self._comm_ctx(options.comm_addr)
         # send/recv arrive here already PAIRED (start() routes the raw
         # halves through the parking maps; _pair merged their endpoint ids)
+        sp = call.begin("plan") if call else None
         tuning = self.tuning()
         options = self._apply_alltoall_wire(options, tuning)
         plan, producer, consumer = self._resolve_step(options, ctx, tuning)
+        if sp:
+            sp.end(algorithm=plan.algorithm.name)
+        sp = call.begin("lower") if call else None
+        compiler = ctx.compiler
+        misses = compiler.lower_misses
         if options.stream_flags:
-            fn = ctx.compiler.lower_streamed(options, plan, producer, consumer)
+            fn = compiler.lower_streamed(options, plan, producer, consumer)
         else:
-            fn = ctx.compiler.lower(options, plan)
+            fn = compiler.lower(options, plan)
+        if sp:
+            sp.end(hit=compiler.lower_misses == misses)
 
         op0 = self._buf(options.addr_0)
         op1 = self._buf(options.addr_1)
@@ -450,47 +466,88 @@ class TPUDevice(CCLODevice):
             if scen == Operation.combine:
                 args.append(self._rows_to_submesh(op1.device, ctx, in_n))
 
+        # the duration register: from before the launch to the host
+        # seeing the result ready (the launch and wait spans inside it)
         with self._launch_mu:  # one collective executable in flight
+            t0 = time.perf_counter_ns()
+            sp = call.begin("launch") if call else None
             out = fn(*args)
+            if sp:
+                sp.end()
+            sp = call.begin("wait") if call else None
             jax.block_until_ready(out)
+            if sp:
+                sp.end()
+            t1 = time.perf_counter_ns()
 
         def place(req):
-            if res is not None and scen != Operation.barrier:
-                if res.device is None:  # host-only result: materialize first
-                    res.sync_to_device()
-                if ctx.rows is None:
-                    res.device = _place_into(res.device, out)
-                else:
-                    res.device = self._scatter_rows(res.device, ctx, out)
+            if res is None or scen == Operation.barrier:
+                return
+            # a child only while its call is still the open one (an
+            # async request may complete after the call span closed)
+            sp = (call.begin("place")
+                  if call and tracer.current() is call else None)
+            copies = self.place_copies
+            if res.device is None:  # host-only result: materialize first
+                res.sync_to_device()
+            if ctx.rows is None:
+                res.device = self._place(res.device, out)
+            else:
+                res.device = self._scatter_rows(res.device, ctx, out)
+            if sp:
+                sp.end(copied=self.place_copies != copies)
 
-        req = TPURequest(options.scenario.name, [out], on_complete=place)
+        req = TPURequest(options.scenario.name, [out], on_complete=place,
+                         duration_ns=t1 - t0)
         req.plan = plan
-        if get_tracer().active:
+        if tracer.active:
             # the facade span drains this: every traced call carries its
             # timing.predict estimate next to the measured duration
-            req.predicted_s = self._predict_call(options, plan, ctx.world)
+            req.predicted_s = self._predict_call(options, plan, ctx.world,
+                                                 fn)
         return req
 
-    def _predict_call(self, options: CallOptions, plan,
-                      world: int) -> float | None:
+    def _place(self, dst, out):
+        """`_place_into`, counting in `place_copies` the writes into a
+        wider buffer, each of which runs a program of its own."""
+        if dst.shape != out.shape:
+            self.place_copies += 1
+        return _place_into(dst, out)
+
+    def _predict_call(self, options: CallOptions, plan, world: int,
+                      program=None) -> float | None:
         """timing.predict estimate for one resolved call under the
         shipped default link (telemetry.feedback.default_link, the same
         calibration autotune consults); None when no timing model is
         committed or the plan has no cost shape. Uses the aggregate
-        cost shape — the regime the shipped emulator fit calibrates."""
-        from ..sequencer.timing import predict
+        cost shape — the regime the shipped emulator fit calibrates.
+
+        With the call's compiled `program`, evaluated once per program
+        and link: the lowering cache keys a program by the op, count,
+        dtype, plan and world the estimate is a pure function of, and
+        its identity is a far cheaper key than those."""
         from ..telemetry.feedback import default_link
 
         link = default_link()
         if link is None or plan is None:
             return None
+        key = (program, self.eager_rx_buf_size)
+        if program is not None:
+            hit = self._predictions.get(key)
+            if hit is not None and hit[0] is link:
+                return hit[1]
+        from ..sequencer.timing import predict
+
         try:
-            return predict(link, options.scenario, plan, options.count,
+            pred = predict(link, options.scenario, plan, options.count,
                            dtype_nbytes(options.data_type), world,
                            rx_buf_bytes=self.eager_rx_buf_size,
                            aggregate=True)
         except (ValueError, KeyError, ZeroDivisionError):
-            return None
+            pred = None
+        if program is not None:
+            self._predictions[key] = (link, pred)
+        return pred
 
     def predict_sequence_cost(self, prepared) -> float | None:
         """Predicted steady-state seconds for ONE dispatch of a
@@ -644,6 +701,9 @@ class TPUDevice(CCLODevice):
                 # recorder can name which admitted set this dispatch
                 # belonged to when it wedges
                 sp.set(interference_cert=prepared.cert)
+            call = tracer.current()
+            if call is not None:  # the facade's sequence span
+                sp.set(call_id=call.args["call_id"])
             args = []
             for addr in seq.buffer_addrs:
                 buf = bufs[addr]
@@ -659,8 +719,16 @@ class TPUDevice(CCLODevice):
             # not let a second tenant's collectives enter the rendezvous
             # before this program's have all arrived, so block inside
             with self._launch_mu:
+                t0 = time.perf_counter_ns()
+                child = sp.begin("launch") if sp.keep else None
                 outs = fn(*args)
+                if child:
+                    child.end()
+                child = sp.begin("wait") if sp.keep else None
                 jax.block_until_ready(outs)
+                if child:
+                    child.end()
+                t1 = time.perf_counter_ns()
 
         out_bufs = [bufs[a] for a in seq.out_addrs]
 
@@ -669,11 +737,12 @@ class TPUDevice(CCLODevice):
                 if buf.device is None:  # host-only result: materialize
                     buf.sync_to_device()
                 if ctx.rows is None:
-                    buf.device = _place_into(buf.device, out)
+                    buf.device = self._place(buf.device, out)
                 else:
                     buf.device = self._scatter_rows(buf.device, ctx, out)
 
-        req = SequenceRequest(list(outs), list(plans), on_complete=place)
+        req = SequenceRequest(list(outs), list(plans), on_complete=place,
+                              duration_ns=t1 - t0)
         # the signature names the program on the request whether or not
         # a tracer is live — telemetry attached later (or a debugger
         # poking a wedged request) must still see which program owns it
@@ -958,7 +1027,7 @@ class TPUDevice(CCLODevice):
         out = prog(placeholder)
 
         def place(req):
-            res.device = _place_into(res.device, out)
+            res.device = self._place(res.device, out)
 
         return TPURequest("stream_put", [out], on_complete=place)
 
@@ -1014,6 +1083,7 @@ class TPUDevice(CCLODevice):
                     if parked.claim():
                         parked._timeout_fire()
             self.compiler._cache.clear()
+            self._predictions.clear()
             self._lint_cache.clear()
             self._comm_cache.clear()
             self._comm_extents.clear()

@@ -218,6 +218,7 @@ def ring_allreduce_pallas(
         compiler_params=pltpu.CompilerParams(
             collective_id=_slot_id(slot, bidir=False, world=world)),
         interpret=interpret,
+        name="ring_allreduce",
     )(x2)
     return out.reshape(padded)[:n]
 
@@ -365,5 +366,6 @@ def ring_allreduce_pallas_bidir(
         compiler_params=pltpu.CompilerParams(
             collective_id=_slot_id(slot, bidir=True, world=world)),
         interpret=interpret,
+        name="ring_allreduce_bidir",
     )(x2)
     return out.reshape(padded)[:n]

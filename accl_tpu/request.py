@@ -44,9 +44,10 @@ class BaseRequest:
 
     def complete(self, retcode: int = 0):
         self.retcode = retcode
-        self.duration_ns = time.perf_counter_ns() - getattr(
-            self, "_start_time", time.perf_counter_ns()
-        )
+        if not self.duration_ns:  # else the backend measured the call
+            self.duration_ns = time.perf_counter_ns() - getattr(
+                self, "_start_time", time.perf_counter_ns()
+            )
         self.status = OperationStatus.COMPLETED
         self._done.set()
         if retcode:
@@ -80,10 +81,14 @@ class BaseRequest:
 
 class TPURequest(BaseRequest):
     """Request whose completion is the readiness of jax output arrays
-    (`block_until_ready`)."""
+    (`block_until_ready`). `duration_ns`, where the device measured it,
+    is the duration register: from before the launch to the host seeing
+    the outputs ready."""
 
-    def __init__(self, function_name: str, outputs, on_complete=None):
+    def __init__(self, function_name: str, outputs, on_complete=None,
+                 duration_ns: int = 0):
         super().__init__(function_name)
+        self.duration_ns = duration_ns
         self.outputs = outputs
         self._on_complete = on_complete
         # set by the device after plan selection: the resolved Plan this
@@ -132,8 +137,10 @@ class SequenceRequest(TPURequest):
     `num_steps` expose what the one dispatch covered, the sequence analog
     of TPURequest.plan."""
 
-    def __init__(self, outputs, plans, on_complete=None):
-        super().__init__("sequence", outputs, on_complete=on_complete)
+    def __init__(self, outputs, plans, on_complete=None,
+                 duration_ns: int = 0):
+        super().__init__("sequence", outputs, on_complete=on_complete,
+                         duration_ns=duration_ns)
         self.plans = list(plans)
         self.num_steps = len(self.plans)
         # set by the device on every dispatch (tracing or not): content

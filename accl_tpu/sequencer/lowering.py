@@ -87,6 +87,9 @@ class ScheduleCompiler:
                 os.environ.get("ACCL_OVERLAP_SERIALIZE") == "1")
         self.overlap_serialize = overlap_serialize
         self._cache: dict = {}
+        # lookups of the cache above that found / built their program
+        self.lower_hits = 0
+        self.lower_misses = 0
 
     # Per-device payload ceiling for the VMEM-resident fused ring kernel;
     # larger transfers fall back to the segmented lax schedule.
@@ -135,7 +138,10 @@ class ScheduleCompiler:
                self.use_pallas_ring, self.pallas_ring_overlap,
                self.overlap_serialize)
         fn = self._cache.get(key)
-        if fn is None:
+        if fn is not None:
+            self.lower_hits += 1
+        else:
+            self.lower_misses += 1
             from ..utils.logging import Log
 
             Log.info("compiling %s: %s/%s world=%d count=%d",
@@ -192,7 +198,10 @@ class ScheduleCompiler:
                self.use_pallas_ring, self.pallas_ring_overlap,
                self.overlap_serialize, "streamed", producer, consumer)
         fn = self._cache.get(key)
-        if fn is None:
+        if fn is not None:
+            self.lower_hits += 1
+        else:
+            self.lower_misses += 1
             body, n_in = self._body(options, plan, arithcfg)
             if producer is not None:
                 if n_in != 1:
@@ -536,7 +545,10 @@ class ScheduleCompiler:
                             self.pallas_ring_overlap,
                             self.overlap_serialize)
         fn = self._cache.get(key)
-        if fn is None:
+        if fn is not None:
+            self.lower_hits += 1
+        else:
+            self.lower_misses += 1
             from ..utils.logging import Log
 
             Log.info(

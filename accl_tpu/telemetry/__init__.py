@@ -58,13 +58,11 @@ from .tracer import (  # noqa: F401
 from .export import (  # noqa: F401
     EVENT_SCHEMA,
     WIRE_FAULT_KEYS,
-    read_trace,
     residual_rows,
     residual_summary,
     to_chrome,
     validate_trace,
     wire_health_report,
-    wire_health_rows,
     write_trace,
 )
 from .feedback import (  # noqa: F401

@@ -166,6 +166,12 @@ EVENT_SCHEMA: dict = {
                             "d_seek_hit": {"type": "integer"},
                             "d_seek_miss": {"type": "integer"},
                             "compute_bytes": {"type": "integer"},
+                            # a facade call and its child phases
+                            # (plan, lower, launch, wait, place,
+                            # stage_in, stage_out) share its call_id
+                            "call_id": {"type": "integer"},
+                            "hit": {"type": "boolean"},
+                            "copied": {"type": "boolean"},
                             # the deadline-miss marker (resilience
                             # host-side verdicts, recorder
                             # .on_deadline_miss): a cat "error" span
@@ -355,18 +361,6 @@ def wire_health_report(stats_by_rank: dict) -> dict:
     return {"per_rank": per_rank, "totals": totals}
 
 
-def wire_health_rows(stats_by_rank: dict) -> list[dict]:
-    """Flat per-rank rows (rank + every counter) for table rendering —
-    the accl_trace/bench printers' shape."""
-    rep = wire_health_report(stats_by_rank)
-    return [{"rank": rank, **row}
-            for rank, row in sorted(rep["per_rank"].items(),
-                                    key=lambda kv: int(kv[0]))]
-
-
 def write_trace(path, trace: dict) -> None:
     pathlib.Path(path).write_text(json.dumps(trace, indent=1))
 
-
-def read_trace(path) -> dict:
-    return json.loads(pathlib.Path(path).read_text())

@@ -208,6 +208,8 @@ class MetricsRegistry:
                                  if label_value_cap is not None
                                  else _label_value_cap())
         self._guarded_values: dict[str, set[str]] = {}
+        # bumped by clear(): series handles held elsewhere are then stale
+        self.generation = 0
         self._counters: dict[tuple[str, LabelsKey], Counter] = {}
         self._gauges: dict[tuple[str, LabelsKey], Gauge] = {}
         self._histograms: dict[tuple[str, LabelsKey], Histogram] = {}
@@ -244,12 +246,6 @@ class MetricsRegistry:
             self.counter("accl_label_overflow_total", label=k).inc()
         return labels
 
-    def guarded_values(self, key: str) -> frozenset[str]:
-        """The attributed value set for a guarded label key (what got a
-        series of its own before the cap)."""
-        with self._mu:
-            return frozenset(self._guarded_values.get(key, ()))
-
     # -- series access -----------------------------------------------------
 
     def counter(self, name: str, **labels: Any) -> Counter:
@@ -282,6 +278,7 @@ class MetricsRegistry:
 
     def clear(self) -> None:
         with self._mu:
+            self.generation += 1
             self._counters.clear()
             self._gauges.clear()
             self._histograms.clear()
@@ -421,12 +418,6 @@ class DriftSentinel:
                 dq = ranks[int(rank)] = deque(maxlen=self.window)
             dq.append(float(measured_s))
 
-    def set_reference(self, op: str, median_rel_err: float) -> None:
-        """Pin an op's reference residual explicitly (e.g. from a
-        committed calibration's known error) instead of self-arming."""
-        with self._mu:
-            self._reference[op] = float(median_rel_err)
-
     def reset(self) -> None:
         with self._mu:
             self._residuals.clear()
@@ -535,6 +526,10 @@ class MetricsObserver:
                  sentinel: DriftSentinel | None = None):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.sentinel = sentinel if sentinel is not None else DriftSentinel()
+        # series labels -> [registry generation, accl_calls_total,
+        # accl_call_seconds once sampled]: one dict hit per call span,
+        # not two label guards and sorts (the label sets here are closed)
+        self._call_series: dict[tuple, list] = {}
 
     def __call__(self, ev: dict[str, Any]) -> None:
         reg = self.registry
@@ -542,13 +537,21 @@ class MetricsObserver:
         args = ev.get("args") or {}
         if cat in ("call", "native"):
             labels = _series_labels(ev, args)
-            reg.counter("accl_calls_total", **labels).inc()
+            key = tuple(labels.values())
+            series = self._call_series.get(key)
+            if series is None or series[0] != reg.generation:
+                series = [reg.generation,
+                          reg.counter("accl_calls_total", **labels), None]
+                self._call_series[key] = series
+            series[1].inc()
             nbytes = args.get("bytes")
             if nbytes:
                 reg.counter("accl_bytes_total", **labels).inc(float(nbytes))
             meas = measured_seconds(ev)
             if meas > 0 and not args.get("dispatch_only"):
-                reg.histogram("accl_call_seconds", **labels).observe(meas)
+                if series[2] is None:
+                    series[2] = reg.histogram("accl_call_seconds", **labels)
+                series[2].observe(meas)
                 pred = args.get("predicted_s")
                 if isinstance(pred, (int, float)):
                     self.sentinel.feed(labels["op"], float(pred), meas)

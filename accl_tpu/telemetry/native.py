@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import time
 
-from ..constants import Operation, TuningParams, dtype_nbytes, DataType
+from ..constants import Operation, TuningParams, dtype_nbytes
 from ..sequencer.plan import select_algorithm
 from ..sequencer.timing import LinkParams, coefficients_aggregate
 
@@ -205,12 +205,6 @@ def drain_world(
     if tracer is not None:
         tracer.extend(events)
     return events, dropped
-
-
-def default_wire_dtype() -> DataType:
-    """Uncompressed wire (native spans never ride compression lanes in
-    the sweeps this module serves)."""
-    return DataType.none
 
 
 __all__ = [

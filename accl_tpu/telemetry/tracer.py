@@ -36,16 +36,33 @@ with `add_observer()` receive every emitted event at span-emission time
 — whether or not the ring itself is collecting — so the streaming
 metrics registry and the flight recorder stay live without a trace ever
 being drained. `span()` returns a live span whenever the tracer is
-`active` (ring enabled OR observers installed); the ring only retains
-events when `enabled`.
+`active` (ring enabled, observers installed, or a profiler session
+collecting); the ring retains events while spans are COLLECTED: the
+ring is enabled, or a JAX profiler session collects.
+
+While a profiler session collects, every span is also a
+`jax.profiler.TraceAnnotation` named `accl.<name>` carrying its scalar
+args, so it lands on the profiler's host plane next to JAX's dispatch
+and the runtime's launch/completion events — the clock the device
+trace is read on. The ring's `ts_ns` stays on `perf_counter_ns`.
+
+Facade calls open a call span with `call()`: it carries a process-wide
+increasing `call_id` and, while spans are collected, becomes the
+thread's `current()` call, under which the device layer opens child
+phase spans (`begin()` / `end()`: plan, lower, launch, wait, place;
+stage_in / stage_out in the facade). When nothing collects, no child
+is built: a child site costs one test of the current call.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
+from typing import Any
 
 SCHEMA_VERSION = "accl-tpu-trace-v1"
 
@@ -53,12 +70,33 @@ SCHEMA_VERSION = "accl-tpu-trace-v1"
 # and counts the drops — mirroring the native ring's contract
 DEFAULT_CAPACITY = 65536
 
+# jax.profiler.TraceAnnotation, bound on the first check after JAX is
+# imported (the tracer itself never imports JAX: a process that has not
+# loaded it has no profiler session to feed)
+_Annotation: Any = None
+
+
+def profiler_collecting() -> bool:
+    """True while a JAX profiler session collects host events."""
+    global _Annotation
+    if _Annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _Annotation = TraceAnnotation
+    return _Annotation.is_enabled()
+
 
 class _NullSpan:
     """Shared no-op span: the disabled-tracing fast path. Reentrant and
     stateless, so one instance serves every call site."""
 
     __slots__ = ()
+    keep = False  # never collected: no children hang off it
+
+    def __bool__(self) -> bool:  # `if sp:` tells a live span from this
+        return False
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -69,6 +107,12 @@ class _NullSpan:
     def set(self, **_kw) -> "_NullSpan":
         return self
 
+    def begin(self, _name: str, **_kw) -> "_NullSpan":
+        return self
+
+    def end(self, **_kw) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -76,21 +120,33 @@ _NULL_SPAN = _NullSpan()
 class _LiveSpan:
     """Context manager measuring one span; emitted into the tracer ring
     on exit. `set()` attaches args discovered mid-span (e.g. the plan a
-    device resolved after dispatch)."""
+    device resolved after dispatch). `keep` says whether spans were
+    being collected when it opened (the ring retains it, and children
+    may hang off it); `annotate` whether a profiler session was (it is
+    then also a TraceAnnotation)."""
 
-    __slots__ = ("_tracer", "name", "cat", "track", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "track", "args", "_t0", "keep",
+                 "_annotate", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, track: str,
-                 args: dict):
+                 args: dict, keep: bool = False, annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.track = track
         self.args = args
         self._t0 = 0
+        self.keep = keep
+        self._annotate = annotate
+        self._ann = None
 
     def __enter__(self) -> "_LiveSpan":
+        # the span's clock encloses its annotation, as a parent's
+        # encloses its children
         self._t0 = time.perf_counter_ns()
+        if self._annotate:
+            self._ann = _Annotation(f"accl.{self.name}")
+            self._ann.__enter__()
         return self
 
     def set(self, **kw) -> "_LiveSpan":
@@ -98,12 +154,77 @@ class _LiveSpan:
         return self
 
     def __exit__(self, exc_type, _exc, _tb) -> bool:
-        dur = time.perf_counter_ns() - self._t0
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self._tracer.emit(self.name, self.cat, self.track,
-                          ts_ns=self._t0, dur_ns=dur, args=self.args)
+        if self._ann is not None:
+            self._ann.set_metadata(**{
+                k: v for k, v in self.args.items()
+                if isinstance(v, (bool, int, float, str))})
+            self._ann.__exit__(None, None, None)
+        self._emit(time.perf_counter_ns() - self._t0)
         return False
+
+    def _emit(self, dur: int) -> None:
+        self._tracer.emit(self.name, self.cat, self.track,
+                          ts_ns=self._t0, dur_ns=dur, args=self.args,
+                          keep=self.keep)
+
+    # -- children: the explicit form for hot paths -------------------------
+
+    def begin(self, name: str, **args) -> "_LiveSpan":
+        """Open a child phase span now, on this span's track and with
+        its `call_id`; close it with `end()`. Only for a span that is
+        collected (`keep`)."""
+        call_id = self.args.get("call_id")
+        if call_id is not None:
+            args["call_id"] = call_id
+        child = _ChildSpan(self._tracer, name, "phase", self.track, args,
+                           True, self._annotate)
+        return child.__enter__()
+
+    def end(self, **args) -> None:
+        """Close a span opened with `begin()`, attaching `args`."""
+        if args:
+            self.args.update(args)
+        self.__exit__(None, None, None)
+
+
+class _ChildSpan(_LiveSpan):
+    """A child phase span. Built only while spans are collected, so it
+    goes to the ring (and the profiler) alone: the observers of the
+    always-on layer see the same events whether or not anyone collects."""
+
+    __slots__ = ()
+
+    def _emit(self, dur: int) -> None:
+        self._tracer._retain({"name": self.name, "cat": self.cat,
+                              "track": self.track, "ts_ns": self._t0,
+                              "dur_ns": dur, "args": self.args})
+
+
+class _CallSpan(_LiveSpan):
+    """The span of one facade call: while collected, the thread's
+    current call for the duration (children read it with
+    `Tracer.current()`)."""
+
+    __slots__ = ("_prev",)
+
+    def __enter__(self) -> "_CallSpan":
+        if self.keep:
+            local = self._tracer._local
+            self._prev = local.call
+            local.call = self
+        super().__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.keep:
+            self._tracer._local.call = self._prev
+        return super().__exit__(exc_type, exc, tb)
+
+
+class _Current(threading.local):
+    call: "_CallSpan | None" = None
 
 
 class Tracer:
@@ -124,6 +245,8 @@ class Tracer:
         # installs/removals copy-on-write under the ring lock
         self._observers: tuple = ()
         self.observer_errors = 0
+        self._local = _Current()
+        self._call_ids = itertools.count(1)
 
     # -- switching ---------------------------------------------------------
 
@@ -133,12 +256,13 @@ class Tracer:
 
     @property
     def active(self) -> bool:
-        """True when spans are worth building: the ring is collecting
-        OR an observability observer (metrics registry, flight
-        recorder) is installed. Emitters gate arg attachment on this,
-        not on `enabled`, so live metrics see the plan/prediction keys
-        even when nobody is recording a full trace."""
-        return self._enabled or bool(self._observers)
+        """True when spans are worth building: the ring is enabled, an
+        observability observer (metrics registry, flight recorder) is
+        installed, or a profiler session collects. Emitters gate arg
+        attachment on this, not on `enabled`, so live metrics see the
+        plan/prediction keys even when nobody is recording a full
+        trace."""
+        return self._enabled or bool(self._observers) or profiler_collecting()
 
     def enable(self) -> None:
         self._enabled = True
@@ -172,19 +296,43 @@ class Tracer:
     def span(self, name: str, cat: str = "call", track: str = "host",
              **args) -> "_NullSpan | _LiveSpan":
         """Start a span context manager. An inactive tracer (ring off,
-        no observers) returns the shared no-op before touching the
-        arguments."""
-        if not (self._enabled or self._observers):
+        no observers, no profiler session) returns the shared no-op
+        before touching the arguments."""
+        prof = profiler_collecting()
+        keep = prof or self._enabled
+        if not (keep or self._observers):
             return _NULL_SPAN
-        return _LiveSpan(self, name, cat, track, args)
+        return _LiveSpan(self, name, cat, track, args, keep, prof)
+
+    def call(self, name: str, cat: str = "call",
+             track: str = "facade") -> "_NullSpan | _CallSpan":
+        """Start the span of one facade call (named by its op): as
+        `span()`, plus a `call_id` that increases through the process,
+        and, while spans are collected, the thread's `current()` call
+        until it closes."""
+        prof = profiler_collecting()
+        keep = prof or self._enabled
+        if not (keep or self._observers):
+            return _NULL_SPAN
+        return _CallSpan(self, name, cat, track,
+                         {"call_id": next(self._call_ids)}, keep, prof)
+
+    def current(self) -> "_CallSpan | None":
+        """The call span this thread is inside while spans are being
+        collected, else None: the one test a child-span site makes."""
+        return self._local.call
 
     def emit(self, name: str, cat: str, track: str, *, ts_ns: int,
-             dur_ns: int, args: dict | None = None) -> None:
+             dur_ns: int, args: dict | None = None,
+             keep: bool | None = None) -> None:
         """Record one already-measured span (the direct form used when
         draining native rings or replaying recorded timings). Observers
-        see every event at emission; the ring retains it only when
-        enabled."""
-        if not (self._enabled or self._observers):
+        see every event at emission; the ring retains it while spans
+        are collected (`keep`: whether they were when the span opened;
+        by default, whether they are now)."""
+        if keep is None:
+            keep = self._enabled or profiler_collecting()
+        if not (keep or self._observers):
             return
         ev = {
             "name": name,
@@ -196,13 +344,8 @@ class Tracer:
         }
         if self._observers:
             self._observe(ev)
-        if not self._enabled:
-            return
-        with self._mu:
-            if len(self._spans) >= self.capacity:
-                self._spans.popleft()
-                self.drops += 1
-            self._spans.append(ev)
+        if keep:
+            self._retain(ev)
 
     def extend(self, events: list[dict]) -> None:
         """Bulk-append pre-shaped span events (ring discipline applies;
@@ -212,8 +355,12 @@ class Tracer:
         if self._observers:
             for ev in events:
                 self._observe(ev)
-        if not self._enabled:
-            return
+        if self._enabled:
+            self._retain(*events)
+
+    def _retain(self, *events: dict) -> None:
+        """Append to the ring, dropping (and counting) the oldest when
+        full."""
         with self._mu:
             for ev in events:
                 if len(self._spans) >= self.capacity:

@@ -97,6 +97,25 @@ def test_mosaic_compiles_ring_kernels_world4(mesh4, variant, dtype):
     _assert_kernel(_ring_program(kernel, mesh4, jax.numpy.dtype(dtype)))
 
 
+@pytest.mark.parametrize("variant,name", [("uni", "ring_allreduce"),
+                                          ("bidir", "ring_allreduce_bidir")])
+def test_ring_kernels_carry_stable_names(mesh4, variant, name):
+    """Each ring kernel names its custom call, so the device trace names
+    the kernel's events by it, not by a numbered `tpu_custom_call`."""
+    from accl_tpu.ops.ring_allreduce import (
+        ring_allreduce_pallas,
+        ring_allreduce_pallas_bidir,
+    )
+
+    kernel = (ring_allreduce_pallas if variant == "uni"
+              else ring_allreduce_pallas_bidir)
+    text = _ring_program(kernel, mesh4, jax.numpy.dtype("float32")).as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(ln.lstrip().startswith(f"%{name}.")
+                         for ln in calls), calls
+
+
 @pytest.mark.parametrize("case", [
     "allreduce_lax", "allreduce_pallas", "allreduce_bf16_wire",
     "allreduce_pallas_64MiB", "bcast", "alltoall", "reduce_scatter",

@@ -183,7 +183,6 @@ def test_guarded_label_overflows_into_other_bucket():
     reg.counter("accl_tenant_dispatches_total", tenant="b").inc()
     reg.counter("accl_tenant_dispatches_total", tenant="c").inc()
     reg.counter("accl_tenant_dispatches_total", tenant="d").inc(2)
-    assert reg.guarded_values("tenant") == {"a", "b"}
     snap = reg.snapshot()
     by_tenant = {r["labels"]["tenant"]: r["value"]
                  for r in snap["counters"]["accl_tenant_dispatches_total"]}
@@ -238,9 +237,12 @@ def test_guard_explicit_other_and_env_cap(monkeypatch):
     reg = MetricsRegistry(label_value_cap=1)
     # writing to the bucket directly is not an overflow event
     reg.counter("accl_tenant_dispatches_total", tenant="other").inc()
-    assert reg.guarded_values("tenant") == set()
     assert "accl_label_overflow_total" not in \
         reg.snapshot()["counters"]
+    # ... nor an admission: the one slot is still free
+    reg.counter("accl_tenant_dispatches_total", tenant="a").inc()
+    assert {r["labels"]["tenant"] for r in reg.snapshot()["counters"][
+        "accl_tenant_dispatches_total"]} == {"other", "a"}
     assert _label_value_cap() == DEFAULT_LABEL_VALUE_CAP
     monkeypatch.setenv("ACCL_METRICS_LABEL_CAP", "3")
     assert _label_value_cap() == 3
@@ -252,9 +254,10 @@ def test_guard_explicit_other_and_env_cap(monkeypatch):
     # clear() resets the admitted set with the series
     reg2 = MetricsRegistry(label_value_cap=1)
     reg2.counter("n", tenant="a").inc()
-    assert reg2.guarded_values("tenant") == {"a"}
     reg2.clear()
-    assert reg2.guarded_values("tenant") == set()
+    reg2.counter("n", tenant="b").inc()
+    (row,) = reg2.snapshot()["counters"]["n"]
+    assert row["labels"] == {"tenant": "b"}
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +309,22 @@ def test_observer_skips_dispatch_only_measurements():
     assert snap["counters"]["accl_calls_total"][0]["value"] == 1.0
     assert "accl_call_seconds" not in snap["histograms"]
     assert obs.sentinel.verdict() == {}
+
+
+def test_observer_series_follow_a_cleared_registry():
+    """The observer keeps its call series' handles; a cleared registry
+    gets fresh ones, so no sample lands in a series nobody can read."""
+    reg = MetricsRegistry()
+    obs = MetricsObserver(reg, DriftSentinel())
+    obs(_call_event())
+    obs(_call_event())
+    reg.clear()
+    obs(_call_event(dur_ns=3_000_000))
+    snap = reg.snapshot()
+    (calls,) = snap["counters"]["accl_calls_total"]
+    (hist,) = snap["histograms"]["accl_call_seconds"]
+    assert calls["value"] == 1.0
+    assert hist["count"] == 1 and hist["sum"] == pytest.approx(3e-3)
 
 
 def test_observer_feeds_straggler_attribution_from_native_ranks():

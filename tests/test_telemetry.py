@@ -422,10 +422,17 @@ def test_facade_and_sequence_spans(tracer, mesh8):
     assert call["args"]["predicted_s"] > 0
     assert call["dur_ns"] > 0
 
-    # the record -> lint -> compile -> dispatch pipeline, one signature
-    phases = {s["name"] for s in by_cat["phase"]}
-    assert {"record", "lint", "compile", "dispatch"} <= phases
-    sigs = {s["args"]["signature"] for s in by_cat["phase"]}
+    # the eager call's children, then the record -> lint -> compile ->
+    # dispatch pipeline, one signature (the dispatch's launch and wait
+    # children carry the sequence call's id instead)
+    call_kids = [s["name"] for s in by_cat["phase"]
+                 if s["args"].get("call_id") == call["args"]["call_id"]]
+    assert call_kids == ["stage_in", "plan", "lower", "launch", "wait",
+                         "place", "stage_out"]
+    seq_phases = [s for s in by_cat["phase"] if "signature" in s["args"]]
+    assert {"record", "lint", "compile", "dispatch"} <= {
+        s["name"] for s in seq_phases}
+    sigs = {s["args"]["signature"] for s in seq_phases}
     assert len(sigs) == 1
 
     # per-step markers carry step index, op, and the predict estimate
@@ -441,6 +448,14 @@ def test_facade_and_sequence_spans(tracer, mesh8):
     assert seq_span["args"]["signature"] in sigs
     assert seq_span["args"]["predicted_s"] == pytest.approx(
         sum(s["args"]["predicted_s"] for s in steps))
+    # the sequence call's children: staging, and the dispatch phase
+    # with its launch and wait; every phase span is accounted for
+    seq_kids = sorted((s for s in by_cat["phase"] if s["args"].get(
+        "call_id") == seq_span["args"]["call_id"]), key=lambda s: s["ts_ns"])
+    assert [s["name"] for s in seq_kids] == [
+        "stage_in", "dispatch", "launch", "wait", "stage_out"]
+    assert len(by_cat["phase"]) == len(
+        {id(s) for s in seq_phases + seq_kids}) + len(call_kids)
 
     # the whole thing round-trips the event schema and the exporter
     trace = tracer.to_trace()
@@ -463,6 +478,257 @@ def test_tracing_off_emits_nothing(mesh8):
     c = accl.create_buffer(n)
     accl.allreduce(a, c, n, ReduceFunction.SUM)
     assert tr.snapshot() == []
+
+
+# ---------------------------------------------------------------------------
+# the eager call's phases: child spans of the facade call span
+# ---------------------------------------------------------------------------
+
+EAGER_PHASES = ["plan", "lower", "launch", "wait", "place"]
+
+
+def _children(spans, call):
+    """The phase spans carrying `call`'s call_id, in start order."""
+    cid = call["args"]["call_id"]
+    return sorted((s for s in spans if s["cat"] == "phase"
+                   and s["args"].get("call_id") == cid),
+                  key=lambda s: s["ts_ns"])
+
+
+def _assert_nested(call, kids):
+    """Each child lies inside its parent, on its track, and no two
+    overlap."""
+    lo, hi = call["ts_ns"], call["ts_ns"] + call["dur_ns"]
+    at = lo
+    for k in kids:
+        assert k["track"] == call["track"]
+        assert k["ts_ns"] >= at, (k["name"], "overlaps its predecessor")
+        at = k["ts_ns"] + k["dur_ns"]
+        assert at <= hi, (k["name"], "ends after its call")
+
+
+@pytest.mark.parametrize("resident", [False, True], ids=["host", "device"])
+def test_eager_call_phases_nest_in_the_call_span(tracer, mesh4, resident):
+    """One eager allreduce on four devices: one call span and its
+    plan/lower/launch/wait/place children, disjoint, inside it, sharing
+    its call_id; a call on host buffers also stages in and out, one with
+    from_device/to_device stages neither."""
+    from accl_tpu.accl import ACCL
+
+    accl = ACCL(mesh4)
+    n = 4096
+    a = accl.create_buffer(n, data=RNG.standard_normal((4, n))
+                           .astype(np.float32))
+    c = accl.create_buffer(n)
+    accl.allreduce(a, c, n, ReduceFunction.SUM)  # compile outside
+    tracer.clear()
+    accl.allreduce(a, c, n, ReduceFunction.SUM, from_device=resident,
+                   to_device=resident)
+    spans = tracer.snapshot()
+    (call,) = [s for s in spans if s["cat"] == "call"]
+    assert call["name"] == "allreduce" and call["track"] == "facade"
+    kids = _children(spans, call)
+    want = EAGER_PHASES if resident else (
+        ["stage_in"] + EAGER_PHASES + ["stage_out"])
+    assert [k["name"] for k in kids] == want
+    assert len(spans) == 1 + len(kids)
+    _assert_nested(call, kids)
+    by_name = {k["name"]: k for k in kids}
+    assert by_name["plan"]["args"]["algorithm"] == call["args"]["algorithm"]
+    assert by_name["lower"]["args"]["hit"] is True
+    assert by_name["place"]["args"]["copied"] is False
+    if not resident:
+        assert by_name["stage_in"]["args"]["bytes"] == a.nbytes
+        assert by_name["stage_out"]["args"]["bytes"] == c.nbytes
+    telemetry.validate_trace(tracer.to_trace())
+
+
+def test_call_ids_increase_through_the_process(tracer, mesh4):
+    from accl_tpu.accl import ACCL
+
+    accl = ACCL(mesh4)
+    n = 256
+    a = accl.create_buffer(n)
+    c = accl.create_buffer(n)
+    for _ in range(3):
+        accl.allreduce(a, c, n, ReduceFunction.SUM)
+    accl.allgather(a, accl.create_buffer(4 * n), n)
+    ids = [s["args"]["call_id"] for s in tracer.snapshot()
+           if s["cat"] == "call"]
+    assert len(ids) == 4 and ids == sorted(set(ids))
+
+
+def test_observers_alone_see_one_span_per_call(mesh4):
+    """With only the always-on observers installed, an eager call emits
+    exactly its call span, no child is built, and the ring stays
+    empty."""
+    from accl_tpu.accl import ACCL
+
+    tr = telemetry.get_tracer()
+    assert not tr.enabled
+    tr.clear()
+    seen = []
+    tr.add_observer(seen.append)
+    try:
+        accl = ACCL(mesh4)
+        n = 512
+        a = accl.create_buffer(n)
+        c = accl.create_buffer(n)
+        for _ in range(3):  # host buffers: staging would be a child
+            accl.allreduce(a, c, n, ReduceFunction.SUM)
+    finally:
+        tr.remove_observer(seen.append)
+    assert [(e["name"], e["cat"]) for e in seen] == [("allreduce", "call")] * 3
+    assert all(e["args"]["predicted_s"] > 0 for e in seen)
+    assert tr.snapshot() == []
+    assert tr.current() is None
+
+
+def _host_events(xplane: str) -> list:
+    import jax
+
+    data = jax.profiler.ProfileData.from_file(xplane)
+    return [e for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("accl.")]
+
+
+def test_profiler_session_collects_spans_on_its_clock(mesh4, tmp_path):
+    """Under a profiler session, the call span and its children are also
+    host-plane annotations (`accl.<name>`, with their args), and the
+    ring keeps the same spans though it was never enabled."""
+    import glob
+
+    import jax
+    from accl_tpu.accl import ACCL
+
+    tr = telemetry.get_tracer()
+    assert not tr.enabled
+    accl = ACCL(mesh4)
+    n = 1024
+    a = accl.create_buffer(n)
+    c = accl.create_buffer(n)
+    accl.allreduce(a, c, n, ReduceFunction.SUM, from_device=True,
+                   to_device=True)
+    tr.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        accl.allreduce(a, c, n, ReduceFunction.SUM, from_device=True,
+                       to_device=True)
+    finally:
+        jax.profiler.stop_trace()
+    ring = tr.drain()
+    assert tr.current() is None
+    (call,) = [s for s in ring if s["cat"] == "call"]
+    assert [k["name"] for k in _children(ring, call)] == EAGER_PHASES
+
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = {e.name: e for e in _host_events(path)}
+    assert {"accl.allreduce"} | {f"accl.{p}" for p in EAGER_PHASES} <= set(
+        events)
+    outer = events["accl.allreduce"]
+    for inner in ("accl.launch", "accl.wait"):
+        e = events[inner]
+        assert outer.start_ns <= e.start_ns
+        assert e.start_ns + e.duration_ns <= outer.start_ns + outer.duration_ns
+        assert dict(e.stats)["call_id"] == call["args"]["call_id"]
+    assert dict(outer.stats)["algorithm"] == call["args"]["algorithm"]
+
+
+def test_lowering_cache_counts_and_lower_span_hit(tracer, mesh4):
+    """The first call of a shape misses the lowering cache, the second
+    hits; the counters move whether or not anything is traced."""
+    from accl_tpu.accl import ACCL
+
+    accl = ACCL(mesh4)
+    comp = accl.cclo.compiler
+    n = 1328  # a shape no other call in this process lowers here
+    a = accl.create_buffer(n)
+    c = accl.create_buffer(n)
+    for _ in range(2):
+        accl.allreduce(a, c, n, ReduceFunction.SUM)
+    hits = [s["args"]["hit"] for s in tracer.snapshot()
+            if s["name"] == "lower"]
+    assert hits == [False, True]
+    assert (comp.lower_misses, comp.lower_hits) == (1, 1)
+    tracer.disable()
+    accl.allreduce(a, c, n, ReduceFunction.SUM)
+    assert (comp.lower_misses, comp.lower_hits) == (1, 2)
+
+
+def test_wider_result_buffer_counts_a_place_copy(tracer, mesh4):
+    from accl_tpu.accl import ACCL
+
+    accl = ACCL(mesh4)
+    dev = accl.cclo
+    n = 256
+    a = accl.create_buffer(n, data=np.ones((4, n), np.float32))
+    exact = accl.create_buffer(n)
+    wide = accl.create_buffer(2 * n)
+    accl.allreduce(a, exact, n, ReduceFunction.SUM)
+    assert dev.place_copies == 0
+    accl.allreduce(a, wide, n, ReduceFunction.SUM)
+    assert dev.place_copies == 1
+    copied = [s["args"]["copied"] for s in tracer.snapshot()
+              if s["name"] == "place"]
+    assert copied == [False, True]
+    np.testing.assert_array_equal(wide.host[:, :n], 4.0)
+
+
+def test_duration_register_covers_launch_and_wait(tracer, mesh4):
+    """get_duration_ns() is the interval from before the launch to the
+    host seeing the result ready: at least the launch and wait spans."""
+    from accl_tpu.accl import ACCL
+
+    accl = ACCL(mesh4)
+    n = 2048
+    a = accl.create_buffer(n)
+    c = accl.create_buffer(n)
+    accl.allreduce(a, c, n, ReduceFunction.SUM)
+    tracer.clear()
+    accl.allreduce(a, c, n, ReduceFunction.SUM)
+    spans = {s["name"]: s for s in tracer.snapshot()}
+    dur = accl.get_duration_ns()
+    assert dur >= spans["launch"]["dur_ns"] + spans["wait"]["dur_ns"]
+    # the register holds the launch, not the staging around it
+    assert dur < spans["allreduce"]["dur_ns"]
+
+
+def test_predict_call_evaluated_once_per_program(mesh4, monkeypatch):
+    """The always-on layer evaluates timing.predict once per compiled
+    program, and every call still carries the same predicted_s."""
+    from accl_tpu.accl import ACCL
+    from accl_tpu.sequencer import timing
+
+    if telemetry.default_link() is None:
+        pytest.skip("no committed timing model")
+    evaluated = []
+    real = timing.predict
+
+    def counting(*a, **kw):
+        evaluated.append(a[1])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(timing, "predict", counting)
+    tr = telemetry.get_tracer()
+    seen = []
+    tr.add_observer(seen.append)
+    try:
+        accl = ACCL(mesh4)
+        n = 768
+        a = accl.create_buffer(n)
+        c = accl.create_buffer(n)
+        for _ in range(4):
+            accl.allreduce(a, c, n, ReduceFunction.SUM)
+    finally:
+        tr.remove_observer(seen.append)
+    assert len(evaluated) == 1
+    preds = {e["args"]["predicted_s"] for e in seen}
+    plan = accl._last_request.plan
+    assert preds == {real(telemetry.default_link(), Operation.allreduce,
+                          plan, n, 4, 4,
+                          rx_buf_bytes=accl.cclo.eager_rx_buf_size,
+                          aggregate=True)}
 
 
 # ---------------------------------------------------------------------------
@@ -833,8 +1099,6 @@ def test_wire_health_report_normalizes_and_totals():
                              "tx_frames": 7}
     assert telemetry.wire_health_report({}) == {"per_rank": {},
                                                 "totals": {}}
-    rows = telemetry.wire_health_rows({1: {"a": 1}, 0: {"a": 2}})
-    assert rows == [{"rank": "0", "a": 2}, {"rank": "1", "a": 1}]
 
 
 def test_wire_health_meta_is_schema_typed():
