@@ -438,7 +438,11 @@ class TPUDevice(CCLODevice):
         else:
             fn = compiler.lower(options, plan)
         if sp:
-            sp.end(hit=compiler.lower_misses == misses)
+            if compiler.lower_misses == misses:
+                sp.end(hit=True)
+            else:  # the ring the new program walks
+                sp.end(hit=False, ring_order=list(compiler.ring_order),
+                       ring_detours=compiler.ring_detours)
 
         op0 = self._buf(options.addr_0)
         op1 = self._buf(options.addr_1)

@@ -19,6 +19,11 @@ for a CPU mesh it executes in Pallas TPU interpret mode, which also gives
 schedule race detection (InterpretParams detect_races) — see
 tests/test_pallas_kernels.py. The caller, who owns the mesh, picks the mode
 with `interpret_for(mesh)`.
+
+The ring is a cycle of mesh positions (`ring`), not mesh index +- 1: the
+caller embeds it in the chips' physical torus with `torus_ring(devices)`,
+so every hop crosses one link whatever order the mesh lists its devices
+in (NCCL's and ACCL's rank tables do the same).
 """
 
 from __future__ import annotations
@@ -61,6 +66,98 @@ def interpret_for(mesh, detect_races: bool = False):
     return pltpu.InterpretParams(detect_races=detect_races)
 
 
+# Largest world whose ring is searched: the chips of one host. Larger
+# meshes keep their own order.
+RING_SEARCH_MAX = 16
+
+
+def _chip_coords(devices) -> list[tuple[int, ...]] | None:
+    """Each device's position in the chips' torus, or None where a device
+    has none (CPU devices)."""
+    coords = [getattr(d, "coords", None) for d in devices]
+    if any(c is None for c in coords):
+        return None
+    return [tuple(c) for c in coords]
+
+
+def _linked(a: tuple[int, ...], b: tuple[int, ...]) -> bool:
+    """Torus neighbours: coordinates one apart in exactly one dimension.
+    No wraparound link is assumed; a one-host slice has none."""
+    return sum(abs(x - y) for x, y in zip(a, b)) == 1
+
+
+def ring_detours(devices, ring: tuple[int, ...]) -> int:
+    """Hops of `ring` (mesh positions in ring order) between devices that
+    are not torus neighbours: each crosses at least two links. 0 where
+    the devices have no coordinates or the ring has one member."""
+    coords = _chip_coords(devices)
+    w = len(ring)
+    if coords is None or w < 2:
+        return 0
+    return sum(not _linked(coords[ring[i]], coords[ring[(i + 1) % w]])
+               for i in range(w))
+
+
+def torus_ring(devices) -> tuple[int, ...]:
+    """The positions of `devices` in an order whose consecutive devices,
+    last and first included, are torus neighbours, found by a small
+    search from position 0. The identity where the devices have no
+    coordinates, the world is at most 2 or above RING_SEARCH_MAX, the
+    identity already is such a cycle, or none exists."""
+    devices = list(devices)
+    w = len(devices)
+    ident = tuple(range(w))
+    coords = _chip_coords(devices)
+    if (coords is None or not 2 < w <= RING_SEARCH_MAX
+            or ring_detours(devices, ident) == 0):
+        return ident
+    links = [[j for j in range(w) if _linked(coords[i], coords[j])]
+             for i in range(w)]
+    path, seen = [0], {0}
+
+    def extend() -> bool:
+        if len(path) == w:
+            return 0 in links[path[-1]]
+        for j in links[path[-1]]:
+            if j not in seen:
+                path.append(j)
+                seen.add(j)
+                if extend():
+                    return True
+                path.pop()
+                seen.discard(j)
+        return False
+
+    return tuple(path) if extend() else ident
+
+
+def _check_ring(ring, world: int) -> tuple[int, ...]:
+    ring = tuple(range(world)) if ring is None else tuple(ring)
+    if sorted(ring) != list(range(world)):
+        raise ValueError(f"ring {ring} is not an order of 0..{world - 1}")
+    return ring
+
+
+def _ring_walk(me, ring: tuple[int, ...]):
+    """`me`'s position on `ring` and the LOGICAL ids of its successor and
+    predecessor there. The identity ring is plain mesh index +- 1, so
+    meshes that keep their own order (one chip, CPU devices) lower with
+    no select chain; any other ring is read through one on the scalar."""
+    world = len(ring)
+    w = jnp.int32(world)
+    if ring == tuple(range(world)):
+        return me, lax.rem(me + 1, w), lax.rem(me + w - 1, w)
+    pos = jnp.int32(0)
+    nxt = jnp.int32(ring[1])
+    prv = jnp.int32(ring[-1])
+    for i in range(1, world):
+        here = me == ring[i]
+        pos = jnp.where(here, i, pos)
+        nxt = jnp.where(here, ring[(i + 1) % world], nxt)
+        prv = jnp.where(here, ring[i - 1], prv)
+    return pos, nxt, prv
+
+
 def _slot_id(slot: int, bidir: bool, world: int) -> int | None:
     """The slot's collective_id, or None at world 1: the kernel then
     takes no barrier semaphore, and Mosaic refuses a collective_id
@@ -79,12 +176,12 @@ def _sublane(dtype) -> int:
     return max(8, 32 // jnp.dtype(dtype).itemsize)
 
 
-def _kernel(axis_name, world, chunk, func, x_ref, o_ref, v_ref, comm_ref,
+def _kernel(axis_name, ring, chunk, func, x_ref, o_ref, v_ref, comm_ref,
             send_sem, recv_sem, credit_sem):
-    me = lax.axis_index(axis_name)
+    # chunk arithmetic runs on ring positions; hops go to ring neighbours
+    world = len(ring)
+    pos, nxt, prv = _ring_walk(lax.axis_index(axis_name), ring)
     w = jnp.int32(world)
-    nxt = lax.rem(me + 1, w)
-    prv = lax.rem(me + w - 1, w)
     total_hops = 2 * (world - 1)
 
     def combine(a, b):
@@ -132,22 +229,22 @@ def _kernel(axis_name, world, chunk, func, x_ref, o_ref, v_ref, comm_ref,
             pltpu.semaphore_signal(credit_sem.at[slot], inc=1, device_id=prv)
 
     # ---- reduce-scatter phase: accumulator starts as our copy of chunk
-    # me-1; the hop-s arrival is the partial of chunk me-2-s (see
+    # pos-1; the hop-s arrival is the partial of chunk pos-2-s (see
     # schedules.reduce_scatter_ring_schedule for the index derivation).
-    v_ref[...] = local_chunk(lax.rem(me + w - 1, w))
+    v_ref[...] = local_chunk(lax.rem(pos + w - 1, w))
     for s in range(world - 1):
         slot = hop(s)
-        idx = lax.rem(me + 2 * w - 2 - s, w)
+        idx = lax.rem(pos + 2 * w - 2 - s, w)
         v_ref[...] = combine(comm_ref[slot], local_chunk(idx))
         release(s, slot)
 
-    # ---- allgather phase: our reduced chunk is chunk `me`; relay P-1
-    # times, filing the hop-s arrival at chunk me-1-s.
-    o_ref[pl.ds(me * chunk, chunk)] = v_ref[...]
+    # ---- allgather phase: our reduced chunk is chunk `pos`; relay P-1
+    # times, filing the hop-s arrival at chunk pos-1-s.
+    o_ref[pl.ds(pos * chunk, chunk)] = v_ref[...]
     for s in range(world - 1):
         t = world - 1 + s
         slot = hop(t)
-        origin = lax.rem(me + 2 * w - 1 - s, w)
+        origin = lax.rem(pos + 2 * w - 1 - s, w)
         v_ref[...] = comm_ref[slot]
         o_ref[pl.ds(origin * chunk, chunk)] = comm_ref[slot]
         release(t, slot)
@@ -180,16 +277,20 @@ def ring_allreduce_pallas(
     interpret,
     func: ReduceFunction = ReduceFunction.SUM,
     slot: int = 0,
+    ring: tuple[int, ...] | None = None,
 ):
     """Per-device body (call inside shard_map): fused ring allreduce of a
     flat (n,) buffer. Pads n up to a world-aligned, lane-aligned chunk.
     `slot` selects an independent semaphore/comm-buffer set (see
-    NUM_RING_SLOTS) so segmented launches can overlap."""
+    NUM_RING_SLOTS) so segmented launches can overlap. `ring` is the
+    mesh positions in the order the ring walks them (`torus_ring`); None
+    walks the mesh's own order."""
+    ring = _check_ring(ring, world)
     f16_detour = _compiled_f16_detour(x, interpret)
     if f16_detour is not None:
         return f16_detour(
             ring_allreduce_pallas, axis_name=axis_name, world=world,
-            func=func, interpret=interpret, slot=slot)
+            func=func, interpret=interpret, slot=slot, ring=ring)
     n = x.shape[-1]
     tile = _sublane(x.dtype) * 128
     chunk = -(-n // world)
@@ -200,7 +301,7 @@ def ring_allreduce_pallas(
     x2 = x.reshape(padded // 128, 128)
     chunk_rows = chunk // 128
 
-    kernel = functools.partial(_kernel, axis_name, world, chunk_rows, func)
+    kernel = functools.partial(_kernel, axis_name, ring, chunk_rows, func)
     out = pl.pallas_call(
         kernel,
         # vma: the output varies across the collective axis (per-device
@@ -231,19 +332,18 @@ def ring_allreduce_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _kernel_bidir(axis_name, world, chunk, func, x_ref, o_ref,
+def _kernel_bidir(axis_name, ring, chunk, func, x_ref, o_ref,
                   vf_ref, vb_ref, commf_ref, commb_ref,
                   sendf_sem, recvf_sem, sendb_sem, recvb_sem,
                   creditf_sem, creditb_sem):
     """Two independent ring pipelines in one kernel: rows [0, world*chunk)
-    flow forward (to rank+1), rows [world*chunk, 2*world*chunk) flow
-    backward (to rank-1). Same RS+AG structure and credit protocol as the
-    unidirectional kernel, with mirrored chunk indexing for the reverse
-    direction."""
-    me = lax.axis_index(axis_name)
+    flow forward (to the ring successor), rows [world*chunk,
+    2*world*chunk) flow backward (to the ring predecessor). Same RS+AG
+    structure and credit protocol as the unidirectional kernel, with
+    mirrored chunk indexing for the reverse direction."""
+    world = len(ring)
+    pos, nxt, prv = _ring_walk(lax.axis_index(axis_name), ring)
     w = jnp.int32(world)
-    nxt = lax.rem(me + 1, w)
-    prv = lax.rem(me + w - 1, w)
     half = world * chunk  # rows in each direction's region
     total_hops = 2 * (world - 1)
 
@@ -288,27 +388,27 @@ def _kernel_bidir(axis_name, world, chunk, func, x_ref, o_ref,
             pltpu.semaphore_signal(creditf_sem.at[slot], inc=1, device_id=prv)
             pltpu.semaphore_signal(creditb_sem.at[slot], inc=1, device_id=nxt)
 
-    # RS phase. Forward direction: start chunk me-1, step-s arrival is
-    # chunk me-2-s. Backward (mirror): start chunk me+1, arrival me+2+s.
-    vf_ref[...] = fwd_chunk(lax.rem(me + w - 1, w))
-    vb_ref[...] = bwd_chunk(lax.rem(me + 1, w))
+    # RS phase. Forward direction: start chunk pos-1, step-s arrival is
+    # chunk pos-2-s. Backward (mirror): start chunk pos+1, arrival pos+2+s.
+    vf_ref[...] = fwd_chunk(lax.rem(pos + w - 1, w))
+    vb_ref[...] = bwd_chunk(lax.rem(pos + 1, w))
     for s in range(world - 1):
         slot = hop(s)
-        fidx = lax.rem(me + 2 * w - 2 - s, w)
-        bidx = lax.rem(me + 2 + s, w)
+        fidx = lax.rem(pos + 2 * w - 2 - s, w)
+        bidx = lax.rem(pos + 2 + s, w)
         vf_ref[...] = combine(commf_ref[slot], fwd_chunk(fidx))
         vb_ref[...] = combine(commb_ref[slot], bwd_chunk(bidx))
         release(s, slot)
 
-    # AG phase. Forward arrival at step s originated at me-1-s; backward
-    # at me+1+s.
-    o_ref[pl.ds(me * chunk, chunk)] = vf_ref[...]
-    o_ref[pl.ds(half + me * chunk, chunk)] = vb_ref[...]
+    # AG phase. Forward arrival at step s originated at pos-1-s; backward
+    # at pos+1+s.
+    o_ref[pl.ds(pos * chunk, chunk)] = vf_ref[...]
+    o_ref[pl.ds(half + pos * chunk, chunk)] = vb_ref[...]
     for s in range(world - 1):
         t = world - 1 + s
         slot = hop(t)
-        forig = lax.rem(me + 2 * w - 1 - s, w)
-        borig = lax.rem(me + 1 + s, w)
+        forig = lax.rem(pos + 2 * w - 1 - s, w)
+        borig = lax.rem(pos + 1 + s, w)
         vf_ref[...] = commf_ref[slot]
         vb_ref[...] = commb_ref[slot]
         o_ref[pl.ds(forig * chunk, chunk)] = commf_ref[slot]
@@ -324,15 +424,18 @@ def ring_allreduce_pallas_bidir(
     interpret,
     func: ReduceFunction = ReduceFunction.SUM,
     slot: int = 0,
+    ring: tuple[int, ...] | None = None,
 ):
     """Bidirectional fused ring allreduce of a flat (n,) buffer. `slot`
     selects an independent semaphore/comm-buffer set (NUM_RING_SLOTS) so
-    segmented launches can double-buffer instead of serializing."""
+    segmented launches can double-buffer instead of serializing. `ring`
+    as in `ring_allreduce_pallas`."""
+    ring = _check_ring(ring, world)
     f16_detour = _compiled_f16_detour(x, interpret)
     if f16_detour is not None:
         return f16_detour(
             ring_allreduce_pallas_bidir, axis_name=axis_name, world=world,
-            func=func, interpret=interpret, slot=slot)
+            func=func, interpret=interpret, slot=slot, ring=ring)
     n = x.shape[-1]
     # pad so n splits into 2 * world whole-tile chunks
     tile = _sublane(x.dtype) * 128
@@ -344,7 +447,7 @@ def ring_allreduce_pallas_bidir(
     x2 = x.reshape(padded // 128, 128)
     chunk_rows = chunk // 128
 
-    kernel = functools.partial(_kernel_bidir, axis_name, world, chunk_rows, func)
+    kernel = functools.partial(_kernel_bidir, axis_name, ring, chunk_rows, func)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype,

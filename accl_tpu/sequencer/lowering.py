@@ -99,6 +99,35 @@ class ScheduleCompiler:
     def world(self) -> int:
         return self.mesh.shape[self.axis_name]
 
+    def _ring_devices(self) -> list | None:
+        """The mesh's devices by collective-axis index, or None where the
+        mesh has none or its other axes hold devices too."""
+        if self.mesh.devices is None:
+            return None
+        devices = list(self.mesh.devices.flat)
+        return devices if len(devices) == self.world else None
+
+    @functools.cached_property
+    def ring_order(self) -> tuple[int, ...]:
+        """Mesh positions in the order the Pallas ring walks them: a cycle
+        of torus neighbours where the devices' coordinates admit one
+        (`ring_allreduce.torus_ring`), the mesh's own order otherwise."""
+        from ..ops.ring_allreduce import torus_ring
+
+        devices = self._ring_devices()
+        if devices is None:
+            return tuple(range(self.world))
+        return torus_ring(devices)
+
+    @functools.cached_property
+    def ring_detours(self) -> int:
+        """Hops of `ring_order` between chips with no link between them."""
+        from ..ops.ring_allreduce import ring_detours
+
+        devices = self._ring_devices()
+        return 0 if devices is None else ring_detours(devices,
+                                                      self.ring_order)
+
     def _wire(
         self,
         options: CallOptions,
@@ -452,10 +481,11 @@ class ScheduleCompiler:
                     seg_elems = max(self.PALLAS_RING_MAX_BYTES // elem_bytes, 1)
 
                     def one_seg(y, slot=0, *, _c=common, _f=func,
-                                _i=interpret_for(self.mesh)):
+                                _i=interpret_for(self.mesh),
+                                _r=self.ring_order):
                         return ring_allreduce_pallas_bidir(
                             y, axis_name=_c["axis"], world=_c["world"],
-                            func=_f, slot=slot, interpret=_i,
+                            func=_f, slot=slot, interpret=_i, ring=_r,
                         )
 
                     def _pallas_ring_body(x, *, _c=common, _seg=seg_elems,

@@ -171,6 +171,10 @@ EVENT_SCHEMA: dict = {
                             # stage_in, stage_out) share its call_id
                             "call_id": {"type": "integer"},
                             "hit": {"type": "boolean"},
+                            # a lowering-cache miss: the compiler's ring
+                            "ring_order": {"type": "array",
+                                           "items": {"type": "integer"}},
+                            "ring_detours": {"type": "integer"},
                             "copied": {"type": "boolean"},
                             # the deadline-miss marker (resilience
                             # host-side verdicts, recorder

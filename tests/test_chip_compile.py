@@ -171,6 +171,48 @@ def test_production_lowering_compiles_world4(mesh4, case):
         assert "tpu_custom_call" not in text
 
 
+def test_ring_order_matches_jax_tray_order(topo):
+    """On a v5e 2x2 the ring search picks the cycle JAX's own mesh
+    builder lays the four chips in."""
+    from jax.experimental import mesh_utils
+
+    from accl_tpu.ops.ring_allreduce import ring_detours, torus_ring
+
+    devices = list(topo.devices[:WORLD])
+    jax_mesh = mesh_utils.create_device_mesh((WORLD,), devices=devices)
+    jax_ring = tuple(devices.index(d) for d in jax_mesh.flat)
+    assert torus_ring(devices) == jax_ring == (0, 1, 3, 2)
+    assert ring_detours(devices, tuple(range(WORLD))) == 2
+
+
+def test_production_lowering_embeds_torus_ring(mesh4):
+    """The compiler over the chips in JAX's device order walks them as
+    (0, 1, 3, 2), with no hop between unlinked chips, and its segmented
+    64 MiB allreduce still compiles with Mosaic under the kernel's name."""
+    from accl_tpu import CallOptions, DataType, Operation, TuningParams
+    from accl_tpu.sequencer import select_algorithm
+    from accl_tpu.sequencer.lowering import ScheduleCompiler
+
+    comp = ScheduleCompiler(mesh4)
+    assert comp.ring_order == (0, 1, 3, 2)
+    assert comp.ring_detours == 0
+    count = 16 * 1024 * 1024
+    opts = CallOptions(scenario=Operation.allreduce, count=count,
+                       function=int(ReduceFunction.SUM),
+                       data_type=DataType.float32)
+    plan = select_algorithm(Operation.allreduce, count, 4, WORLD,
+                            max_eager_size=1 << 30,
+                            eager_rx_buf_size=1 << 22,
+                            tuning=TuningParams.default())
+    x = jax.ShapeDtypeStruct((WORLD, count), np.float32,
+                             sharding=NamedSharding(mesh4, P("ccl")))
+    text = comp.lower(opts, plan).lower(x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(ln.lstrip().startswith("%ring_allreduce_bidir.")
+                         for ln in calls), calls
+
+
 @pytest.mark.parametrize("nbytes", [256 * 1024, 64 * 1024 * 1024])
 def test_production_lowering_compiles_world1(topo, nbytes):
     """A world-1 allreduce on one chip (the facade on a 1-device mesh)

@@ -55,13 +55,41 @@ def test_fused_combine_cast():
                                rtol=1e-2, atol=1e-2)
 
 
-@pytest.mark.parametrize("world,n", [(4, 1024), (8, 2048), (8, 1000), (2, 256)])
-def test_ring_allreduce_kernel(world, n):
+SUM, MAX = ReduceFunction.SUM, ReduceFunction.MAX
+RING8 = (0, 1, 2, 3, 7, 6, 5, 4)  # a 2x4 tray's neighbour cycle
+
+
+def _ring_cases(base):
+    """The mesh-order cases (ids kept as they were) plus explicit rings:
+    the result is the same whatever order the ring walks the devices
+    in, for SUM and MAX."""
+    cases = [pytest.param(w, n, None, SUM, id=f"{w}-{n}") for w, n in base]
+    for w, n, ring, func in [
+        (4, base[0][1], (0, 1, 3, 2), SUM),
+        (4, base[0][1], (3, 2, 1, 0), MAX),
+        (4, base[0][1] - 24, (0, 2, 1, 3), MAX),
+        (4, base[0][1], (0, 2, 1, 3), SUM),
+        (8, base[1][1], RING8, MAX),
+        (8, base[1][1] - 24, RING8, SUM),
+    ]:
+        label = "".join(map(str, ring))
+        cases.append(pytest.param(w, n, ring, func,
+                                  id=f"{w}-{n}-ring{label}-{func.name.lower()}"))
+    return cases
+
+
+def _expected(x, func):
+    return np.tile(x.sum(0) if func == SUM else x.max(0), (x.shape[0], 1))
+
+
+@pytest.mark.parametrize("world,n,ring,func", _ring_cases(
+    [(4, 1024), (8, 2048), (8, 1000), (2, 256)]))
+def test_ring_allreduce_kernel(world, n, ring, func):
     devs = np.array(jax.devices()[:world])
     mesh = Mesh(devs, ("ccl",))
     body = functools.partial(
         ring_allreduce_pallas, axis_name="ccl", world=world,
-        func=ReduceFunction.SUM, interpret=interpret_for(mesh),
+        func=func, interpret=interpret_for(mesh), ring=ring,
     )
     fn = jax.jit(
         jax.shard_map(
@@ -74,8 +102,7 @@ def test_ring_allreduce_kernel(world, n):
     )
     x = RNG.standard_normal((world, n)).astype(np.float32)
     out = np.asarray(fn(x))
-    np.testing.assert_allclose(out, np.tile(x.sum(0), (world, 1)),
-                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out, _expected(x, func), rtol=1e-4, atol=1e-4)
 
 
 def test_ring_allreduce_race_detector():
@@ -120,15 +147,18 @@ def test_pallas_ring_through_facade(mesh8):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("world,n", [(4, 2048), (8, 4000), (2, 512)])
-def test_bidirectional_ring_allreduce(world, n):
+@pytest.mark.parametrize("world,n,ring,func", _ring_cases(
+    [(4, 2048), (8, 4000), (2, 512)]))
+def test_bidirectional_ring_allreduce(world, n, ring, func):
+    """World-4 cases, explicit rings among them, run under the TPU
+    interpreter's race detector."""
     from accl_tpu.ops.ring_allreduce import ring_allreduce_pallas_bidir
 
     devs = np.array(jax.devices()[:world])
     mesh = Mesh(devs, ("ccl",))
     body = functools.partial(
         ring_allreduce_pallas_bidir, axis_name="ccl", world=world,
-        func=ReduceFunction.SUM,
+        func=func, ring=ring,
         interpret=interpret_for(mesh, detect_races=(world == 4)),
     )
     fn = jax.jit(
@@ -142,8 +172,7 @@ def test_bidirectional_ring_allreduce(world, n):
     )
     x = RNG.standard_normal((world, n)).astype(np.float32)
     out = np.asarray(fn(x))
-    np.testing.assert_allclose(out, np.tile(x.sum(0), (world, 1)),
-                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out, _expected(x, func), rtol=1e-4, atol=1e-4)
 
 
 def test_pallas_ring_segmented_large_payload(mesh8):

@@ -647,9 +647,14 @@ def test_lowering_cache_counts_and_lower_span_hit(tracer, mesh4):
     c = accl.create_buffer(n)
     for _ in range(2):
         accl.allreduce(a, c, n, ReduceFunction.SUM)
-    hits = [s["args"]["hit"] for s in tracer.snapshot()
-            if s["name"] == "lower"]
-    assert hits == [False, True]
+    lowers = [s["args"] for s in tracer.snapshot() if s["name"] == "lower"]
+    assert [a["hit"] for a in lowers] == [False, True]
+    # the miss names the ring its program walks: CPU devices have no
+    # coordinates, so the mesh's own order, with no detour counted
+    assert lowers[0]["ring_order"] == [0, 1, 2, 3]
+    assert lowers[0]["ring_detours"] == 0
+    assert "ring_order" not in lowers[1]
+    telemetry.validate_trace(tracer.to_trace())
     assert (comp.lower_misses, comp.lower_hits) == (1, 1)
     tracer.disable()
     accl.allreduce(a, c, n, ReduceFunction.SUM)
