@@ -16,6 +16,7 @@ on buffer identity.
 
 from __future__ import annotations
 
+import functools
 import itertools
 
 import jax
@@ -60,7 +61,9 @@ class TPUBuffer(BaseBuffer):
     The host mirror (`host`) is numpy; `device` is the sharded jax.Array.
     sync_to_device/sync_from_device move whole images, like the reference's
     explicit DMA syncs (buffer.hpp:60-72) — collectives can then run
-    `from_fpga/to_fpga`-style without host round-trips.
+    `from_fpga/to_fpga`-style without host round-trips. put_prefix and
+    fetch_row move part of an image: a prefix of every row in, one
+    rank's row out.
     """
 
     def __init__(self, host: np.ndarray, sharding, host_only: bool = False):
@@ -95,6 +98,36 @@ class TPUBuffer(BaseBuffer):
             self.host = np.asarray(jax.device_get(self.device))
         return self
 
+    def put_prefix(self, rows: np.ndarray) -> int:
+        """Write `rows` (world, k) over the first k elements of every
+        rank row, in the host mirror and in the device image; the rest
+        of both stays as it was. Only the prefix crosses to the device,
+        written in place into the (donated) image. Returns the bytes
+        put on the device."""
+        rows = np.ascontiguousarray(rows, self.np_dtype)
+        if self.host is not None:
+            if not self.host.flags.writeable:  # as sync_from_device left it
+                self.host = self.host.copy()
+            self.host[:, :rows.shape[1]] = rows
+        if self.device is None:
+            return 0
+        self.device = _prefix_writer(self.sharding)(
+            self.device, jax.device_put(rows, self.sharding))
+        return rows.nbytes
+
+    def fetch_row(self, rank: int, count: int) -> np.ndarray:
+        """The first `count` elements of rank `rank`'s device row, read
+        from that rank's shard alone; the host mirror is left as it
+        was."""
+        for shard in self.device.addressable_shards:
+            first, stop, _ = shard.index[0].indices(self.shape[0])
+            if first <= rank < stop:
+                data = shard.data
+                if count < data.shape[1]:  # cut on the device
+                    return np.asarray(data[rank - first, :count])
+                return np.asarray(data)[rank - first]
+        raise ValueError(f"rank {rank}'s row is on no addressable device")
+
     def write(self, data: np.ndarray):
         data = np.asarray(data, self.np_dtype).reshape(self.shape)
         self.host = data
@@ -103,6 +136,16 @@ class TPUBuffer(BaseBuffer):
     def rank_view(self, rank: int) -> np.ndarray:
         """Host view of one rank's buffer."""
         return self.host[rank]
+
+
+@functools.lru_cache(maxsize=None)
+def _prefix_writer(sharding):
+    """image, prefix -> image with the prefix written over the start of
+    every row, the image donated: one program per sharding."""
+    return jax.jit(
+        lambda image, prefix: jax.lax.dynamic_update_slice(
+            image, prefix, (0, 0)),
+        out_shardings=sharding, donate_argnums=0)
 
 
 class EmuBuffer(BaseBuffer):

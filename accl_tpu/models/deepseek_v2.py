@@ -654,8 +654,9 @@ class DeepSeekV2:
                             res_stream=logits_stream(self.cfg),
                             dstbuf=buffers.logits, from_device=True)
 
-    def write_inputs(self, buffers, tokens, pos) -> None:
-        trf.write_decode_inputs(buffers, {"embed": self.embed}, tokens, pos)
+    def write_inputs(self, buffers, tokens, pos) -> int:
+        return trf.write_decode_inputs(buffers, {"embed": self.embed},
+                                       tokens, pos)
 
     def read_logits(self, buffers, sync: bool = False) -> np.ndarray:
         return trf.read_decode_logits(buffers, sync=sync)

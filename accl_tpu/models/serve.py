@@ -78,8 +78,9 @@ class DecodeServer:
 
     `model` is a decode model (`transformer.FlagshipDecode`,
     `deepseek_v2.DeepSeekV2`): its `create_buffers`, `make_program`,
-    `register_consumers`, `run_eager`, `write_inputs`, `read_logits` and
-    `prefill` (None where it has none)."""
+    `register_consumers`, `run_eager`, `write_inputs` (which returns the
+    bytes it put on the device), `read_logits` and `prefill` (None where
+    it has none)."""
 
     def __init__(self, accl, model, *, batch: int,
                  max_len: int, mode: str = "fused", lint: str = "error",
@@ -107,10 +108,10 @@ class DecodeServer:
         # consults its backpressure (typed SchedulerSaturatedError
         # when the ring is saturated) and every fused step dispatches
         # through scheduler.dispatch_now — the same program, the same
-        # run(to_device=True), so batched==sequential bitwise parity
-        # is untouched; what the scheduler adds is tenant metering,
-        # SLO residuals and the concurrency/certificate discipline
-        # next to any co-running tenants.
+        # run(from_device=True, to_device=True), so batched==sequential
+        # bitwise parity is untouched; what the scheduler adds is tenant
+        # metering, SLO residuals and the concurrency/certificate
+        # discipline next to any co-running tenants.
         self._scheduler = scheduler
         self._tenant = tenant
         self._step_cost_s: float | None = None
@@ -196,7 +197,8 @@ class DecodeServer:
         argmax tokens, retire finished requests. Returns the number of
         generated (non-prompt) tokens this step. The step is one `call`
         span (while spans are collected) with children `decode.inputs`
-        and `decode.logits`; the sequence's dispatch carries its id."""
+        and `decode.logits`, each with the `bytes` it moved to or from
+        the device; the sequence's dispatch carries its id."""
         self.admit()
         with get_tracer().call("decode_step") as sp:
             return self._step(sp if sp.keep else None)
@@ -218,24 +220,30 @@ class DecodeServer:
         if call:  # what the step computes: its tokens and attended rows
             live = [s.pos + 1 for s in self._slots if s is not None]
             call.set(slots=len(live), context=sum(live))
-        self.model.write_inputs(self._buffers, tokens, pos)
+        moved = self.model.write_inputs(self._buffers, tokens, pos)
         if ph:
-            ph.end()
+            ph.end(bytes=moved)
         t0 = self._time()
         if self._program is not None:
-            # steady state: one dispatch; kv caches stay device-resident
+            # steady state: one dispatch over device-resident buffers
+            # (write_inputs put xp's [x, pos] prefix on the device, the
+            # only bytes the step reads from the host); one rank's
+            # logits come back
             if self._scheduler is not None:
                 self._scheduler.dispatch_now(self._tenant,
                                              self._program,
+                                             from_device=True,
                                              to_device=True)
             else:
-                self._program.run(to_device=True)
+                self._program.run(from_device=True, to_device=True)
             ph = call.begin("decode.logits") if call else None
             logits = self.model.read_logits(self._buffers, sync=True)
+            moved = logits.nbytes
         else:
             self.model.run_eager(self._accl, self._buffers)
             ph = call.begin("decode.logits") if call else None
             logits = self.model.read_logits(self._buffers)
+            moved = 0  # the eager twin's last call brought them back
         self.last_logits = logits
         dt = self._time() - t0
         n_generated = 0
@@ -256,7 +264,7 @@ class DecodeServer:
                 r.done = True
                 self._slots[i] = None  # leave at the boundary
         if ph:
-            ph.end()
+            ph.end(bytes=moved)
         self.n_steps += 1
         self._m_step.observe(dt)
         if n_generated:

@@ -1261,18 +1261,22 @@ def run_decode_step_eager(accl, cfg: TransformerConfig,
 
 
 def write_decode_inputs(buffers: DecodeBuffers, params: dict, tokens,
-                        pos):
+                        pos) -> int:
     """Stage one step's inputs: embed `tokens` (B,) at per-slot
-    positions `pos` (B,) into every rank row of the xp buffer — the
-    decode loop's host half (identical rows: the embedding is
-    replicated, exactly like the sharded model's)."""
+    positions `pos` (B,) into the [x, pos] prefix of every rank row of
+    the xp buffer — the decode loop's host half (identical rows: the
+    embedding is replicated, exactly like the sharded model's). The
+    prefix lands in the host mirror and in xp's device image, and the
+    step reads nothing of xp past it, so `program.run(from_device=True)`
+    needs no other staging. Returns the bytes put on the device."""
     d = buffers.dims
-    x0 = np.asarray(params["embed"])[np.asarray(tokens, np.int64)]
-    row = np.zeros(d.n_out, np.float32)
-    row[:d.batch * d.d_model] = x0.reshape(-1)
-    row[d.batch * d.d_model:d.batch * d.d_model + d.batch] = (
-        np.asarray(pos, np.float32))
-    buffers.xp.host[:] = row[None]
+    b_d = d.batch * d.d_model
+    row = np.empty(b_d + d.batch, np.float32)
+    row[:b_d] = np.asarray(params["embed"])[
+        np.asarray(tokens, np.int64)].reshape(-1)
+    row[b_d:] = np.asarray(pos, np.float32)
+    xp = buffers.xp
+    return xp.put_prefix(np.broadcast_to(row, (xp.shape[0], row.size)))
 
 
 def read_decode_logits(buffers: DecodeBuffers, *,
@@ -1280,14 +1284,13 @@ def read_decode_logits(buffers: DecodeBuffers, *,
     """The step's logits (B, V) from rank row 0 (replicated head).
     Pass sync=True after `program.run(to_device=True)` — the
     steady-state dispatch form that keeps the kv caches device-resident
-    and syncs ONLY the logits buffer back (the eager twin's final
-    copy_to_stream already lands host-side)."""
+    — to read them from rank 0's device row alone (the eager twin's
+    final copy_to_stream already lands host-side)."""
     d = buffers.dims
-    if sync:
-        buffers.logits.sync_from_device()
-    return np.asarray(
-        buffers.logits.host[0][:d.batch * d.vocab],
-        np.float32).reshape(d.batch, d.vocab)
+    n = d.batch * d.vocab
+    row = (buffers.logits.fetch_row(0, n) if sync
+           else buffers.logits.host[0][:n])
+    return np.asarray(row, np.float32).reshape(d.batch, d.vocab)
 
 
 class FlagshipDecode:
@@ -1322,8 +1325,8 @@ class FlagshipDecode:
     def run_eager(self, accl, buffers):
         return run_decode_step_eager(accl, self.cfg, buffers)
 
-    def write_inputs(self, buffers, tokens, pos) -> None:
-        write_decode_inputs(buffers, self.params, tokens, pos)
+    def write_inputs(self, buffers, tokens, pos) -> int:
+        return write_decode_inputs(buffers, self.params, tokens, pos)
 
     def read_logits(self, buffers, sync: bool = False) -> np.ndarray:
         return read_decode_logits(buffers, sync=sync)

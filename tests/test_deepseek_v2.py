@@ -94,6 +94,66 @@ def test_fused_equals_eager_bitwise(params):
             err_msg=f"seed {seed}: fused != eager")
 
 
+def test_prefix_in_row_out_equals_whole_images_bitwise(params):
+    """Ten chained steps: xp's [x, pos] prefix put on the device, the
+    step run over device-resident buffers and rank 0's logits row read
+    give the logits of staging xp's whole image and reading every
+    rank's logits back, bit for bit."""
+    pa, pw = _accl(2), _accl(2)
+    ma = ds.DeepSeekV2(pa, CFG, params=params)
+    mw = ds.DeepSeekV2(pw, CFG, params=params)
+    ba, bw = ma.create_buffers(pa, 3, 16), mw.create_buffers(pw, 3, 16)
+    prog_a, prog_w = ma.make_program(pa, ba), mw.make_program(pw, bw)
+    d = bw.dims
+    b_d = d.batch * d.d_model
+    for seed in range(10):
+        rng = np.random.default_rng(7200 + seed)
+        toks = rng.integers(0, CFG.vocab_size, 3)
+        pos = rng.integers(0, 16, 3)
+        ma.write_inputs(ba, toks, pos)
+        prog_a.run(from_device=True, to_device=True)
+        row = np.zeros(d.n_out, np.float32)
+        row[:b_d] = params["embed"][toks].reshape(-1)
+        row[b_d:b_d + d.batch] = pos
+        bw.xp.host = np.repeat(row[None], 2, 0)
+        prog_w.run(to_device=True)
+        bw.logits.sync_from_device()
+        np.testing.assert_array_equal(
+            ma.read_logits(ba, sync=True),
+            bw.logits.host[0][:d.batch * d.vocab].reshape(d.batch, d.vocab),
+            err_msg=f"step {seed}: prefix path != whole images")
+
+
+def test_served_step_moves_the_prefix_in_and_one_row_out(params):
+    """Each served step's decode.inputs span counts world x (B*D + B)
+    floats put on the device, its decode.logits span B*V read back."""
+    from accl_tpu import telemetry
+
+    world, batch = 4, 3
+    accl = _accl(world)
+    srv = serve.DecodeServer(accl, ds.DeepSeekV2(accl, CFG, params=params,
+                                                 prefill_chunk=4),
+                             batch=batch, max_len=24)
+    for n in (5, 9):
+        srv.submit([1], 6, context=list(range(2, 2 + n)))
+    srv.step()
+    tr = telemetry.get_tracer()
+    tr.clear()
+    tr.enable()
+    try:
+        for _ in range(2):
+            srv.step()
+        ring = tr.snapshot()
+    finally:
+        tr.disable()
+        tr.clear()
+    moved = {name: [s["args"]["bytes"] for s in ring if s["name"] == name]
+             for name in ("decode.inputs", "decode.logits")}
+    assert moved == {
+        "decode.inputs": [world * (batch * CFG.hidden_size + batch) * 4] * 2,
+        "decode.logits": [batch * CFG.vocab_size * 4] * 2}
+
+
 def test_expert_shares_add_up_to_the_layer(params):
     """Four ranks, two routed experts and a quarter of the shared
     expert's width each: their partial FFN outputs, the shared expert

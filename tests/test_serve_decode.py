@@ -87,6 +87,164 @@ def test_fused_vs_eager_fuzz_bitwise():
             lf, le, err_msg=f"seed {seed}: fused != eager (bitwise)")
 
 
+def _whole_image_step(prog, buffers, embed, toks, pos):
+    """A step that stages xp's whole image in and reads every rank's
+    logits back: the host mirror rebuilt in full, run(to_device=True),
+    then sync_from_device and row 0."""
+    d = buffers.dims
+    b_d = d.batch * d.d_model
+    row = np.zeros(d.n_out, np.float32)
+    row[:b_d] = np.asarray(embed)[np.asarray(toks, np.int64)].reshape(-1)
+    row[b_d:b_d + d.batch] = np.asarray(pos, np.float32)
+    buffers.xp.host = np.repeat(row[None], buffers.xp.shape[0], 0)
+    prog.run(to_device=True)
+    buffers.logits.sync_from_device()
+    return buffers.logits.host[0][:d.batch * d.vocab].reshape(d.batch,
+                                                               d.vocab)
+
+
+def _prefix_step(prog, buffers, params, toks, pos):
+    """The serving step's I/O: the [x, pos] prefix in, the step run
+    over device-resident buffers, rank 0's logits out."""
+    trf.write_decode_inputs(buffers, params, toks, pos)
+    prog.run(from_device=True, to_device=True)
+    return trf.read_decode_logits(buffers, sync=True)
+
+
+def test_prefix_in_row_out_equals_whole_images_bitwise():
+    """Twelve chained steps of random tokens at ragged positions: the
+    prefix put, run(from_device=True) and rank 0's row read give the
+    logits of whole-image staging and a full read back, bit for bit."""
+    params_np = _params_np(seed=4)
+    prog_p, bp = _fused(params_np)
+    prog_w, bw = _fused(params_np)
+    for seed in range(12):
+        rng = np.random.default_rng(53000 + seed)
+        toks = rng.integers(1, CFG.vocab, B)
+        pos = rng.integers(0, T, B)
+        np.testing.assert_array_equal(
+            _prefix_step(prog_p, bp, params_np, toks, pos),
+            _whole_image_step(prog_w, bw, params_np["embed"], toks, pos),
+            err_msg=f"step {seed}: prefix path != whole images")
+
+
+def test_eager_twin_interleaved_with_prefix_steps_bitwise():
+    """The eager twin (which stages xp from the host mirror) and the
+    prefix-only fused step take turns on ONE set of buffers; each step
+    matches a fused step on buffers of its own, bit for bit."""
+    params_np = _params_np(seed=5)
+    accl = ACCL(_mesh())
+    prog, bf = trf.make_decode_step_program(accl, CFG, params_np, batch=B,
+                                            max_len=T)
+    prog_r, br = _fused(params_np)
+    for seed in range(12):
+        rng = np.random.default_rng(54000 + seed)
+        toks = rng.integers(1, CFG.vocab, B)
+        pos = rng.integers(0, T, B)
+        if seed % 3 == 1:
+            trf.write_decode_inputs(bf, params_np, toks, pos)
+            trf.run_decode_step_eager(accl, CFG, bf)
+            got = trf.read_decode_logits(bf)
+        else:
+            got = _prefix_step(prog, bf, params_np, toks, pos)
+        np.testing.assert_array_equal(
+            got, _prefix_step(prog_r, br, params_np, toks, pos),
+            err_msg=f"step {seed}: interleaved != fused alone")
+
+
+def _traced_steps(srv, steps):
+    """The tracer ring's spans over `steps` server steps."""
+    from accl_tpu import telemetry
+
+    tr = telemetry.get_tracer()
+    tr.clear()
+    tr.enable()
+    try:
+        for _ in range(steps):
+            srv.step()
+        return tr.snapshot()
+    finally:
+        tr.disable()
+        tr.clear()
+
+
+def test_steady_step_spans_count_the_prefix_and_one_row():
+    """A served fused step puts world x (B*D + B) floats on the device
+    and reads B*V back, and stages nothing else; the eager server's
+    logits arrive with its last call, so its read moves nothing."""
+    params_np = _params_np(seed=6)
+    for mode in ("fused", "eager"):
+        srv = serve.DecodeServer(ACCL(_mesh()),
+                                 trf.FlagshipDecode(CFG, params_np),
+                                 batch=B, max_len=T, mode=mode)
+        srv.submit([3, 5], 6)
+        srv.submit([7], 6)
+        srv.step()  # the first step compiles the prefix writer
+        ring = _traced_steps(srv, 3)
+        ins, outs = ([s for s in ring if s["name"] == name]
+                     for name in ("decode.inputs", "decode.logits"))
+        assert len(ins) == len(outs) == 3
+        assert {s["args"]["bytes"] for s in ins} == {
+            WORLD * (B * CFG.d_model + B) * 4}
+        assert {s["args"]["bytes"] for s in outs} == {
+            B * CFG.vocab * 4 if mode == "fused" else 0}
+        if mode == "fused":
+            assert not [s for s in ring
+                        if s["name"] in ("stage_in", "stage_out")]
+
+
+def test_whole_image_syncs_still_move_whole_images():
+    """sync_to_device / sync_from_device move every rank's whole row;
+    put_prefix writes only the prefix of each row (host mirror and
+    device image alike) and fetch_row reads one rank's row."""
+    accl = ACCL(_mesh())
+    n, k = 40, 7
+    buf = accl.create_buffer(n, np.float32)
+    image = np.arange(WORLD * n, dtype=np.float32).reshape(WORLD, n)
+    buf.host = image.copy()
+    buf.sync_to_device()
+    np.testing.assert_array_equal(np.asarray(buf.device), image)
+    rows = -np.arange(WORLD * k, dtype=np.float32).reshape(WORLD, k) - 1
+    assert buf.put_prefix(rows) == rows.nbytes
+    want = image.copy()
+    want[:, :k] = rows
+    np.testing.assert_array_equal(buf.host, want)
+    np.testing.assert_array_equal(np.asarray(buf.device), want)
+    for r in range(WORLD):
+        np.testing.assert_array_equal(buf.fetch_row(r, n), want[r])
+        np.testing.assert_array_equal(buf.fetch_row(r, k + 2),
+                                      want[r, :k + 2])
+    np.testing.assert_array_equal(buf.host, want)  # fetch_row left it
+    buf.host = np.zeros_like(image)
+    buf.sync_from_device()
+    assert buf.host.shape == (WORLD, n)
+    np.testing.assert_array_equal(buf.host, want)
+    # a whole-image run stages xp's whole image in, and its outputs out
+    from accl_tpu import telemetry
+
+    prog, bf = _fused(_params_np())
+    trf.write_decode_inputs(bf, _params_np(), [1, 2], [0, 0])
+    tr = telemetry.get_tracer()
+    tr.clear()
+    tr.enable()
+    try:
+        prog.run()
+        ring = tr.snapshot()
+    finally:
+        tr.disable()
+        tr.clear()
+    (stage_in,) = [s for s in ring if s["name"] == "stage_in"]
+    assert stage_in["args"]["bytes"] == bf.xp.nbytes
+    (stage_out,) = [s for s in ring if s["name"] == "stage_out"]
+    assert stage_out["args"]["bytes"] >= bf.xp.nbytes + bf.logits.nbytes
+    # the read-only mirror that sync_from_device leaves takes a prefix
+    trf.write_decode_inputs(bf, _params_np(), [3, 4], [1, 1])
+    b_d = B * CFG.d_model
+    np.testing.assert_array_equal(bf.xp.host[:, b_d:b_d + B],
+                                  np.ones((WORLD, B)))
+    np.testing.assert_array_equal(np.asarray(bf.xp.device), bf.xp.host)
+
+
 def test_fused_decode_matches_full_forward_oracle():
     """KV-cache correctness: decoding a sequence token by token through
     the fused program reproduces the full-context training forward
