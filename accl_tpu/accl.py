@@ -366,8 +366,7 @@ class ACCL:
         """Stage, start, complete one prepared call inside its call
         span `sp`, which the public method opened at its entry
         (get_tracer().call: the shared no-op when telemetry is off,
-        one predicate; the bench smoke path gates the disabled cost
-        <1%)."""
+        one predicate)."""
         # armed deadlines (resilience seam): time the synchronous call
         # end to end so the manager can check it against its
         # model-derived deadline after completion. async calls complete

@@ -7,9 +7,8 @@ semantic certifier (semantics.py, when per-step Plans are available),
 and optionally the deep per-rank protocol interpretation (protocol.py)
 — and returns the combined diagnostic list, most severe first.
 
-The shallow passes are pure Python over the descriptors (microseconds;
-the bench smoke gate pins the whole default tier under 5% of
-record+compile time). The semantic pass (ACCL501-504) is per-batch
+The shallow passes are pure Python over the descriptors
+(microseconds). The semantic pass (ACCL501-504) is per-batch
 LINEAR — one contribution-set abstract evaluation per step, verdicts
 cached by static signature — so it rides the DEFAULT tier; only
 pathologically segmented shapes defer to the CLI/CI sweep (see

@@ -454,7 +454,7 @@ def iter_ppermute_eqns(jaxpr):
     through eqn-param sub-jaxprs (jit bodies, shard_map, scan/cond
     branches), in trace order. THE walker for the 'every cross-rank hop
     is a ppermute' invariant — the protocol pass reads perms from it and
-    bench.py's wire-byte audit sums operand bytes over it, so a jax
+    the wire-byte tests sum operand bytes over it, so a jax
     version changing eqn param shapes gets fixed in exactly one place."""
     inner = getattr(jaxpr, "jaxpr", jaxpr)  # unwrap ClosedJaxpr
     for eqn in inner.eqns:

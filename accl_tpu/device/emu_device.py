@@ -240,7 +240,7 @@ class EmuRank:
         with a sticky RECEIVE_TIMEOUT retcode (a final trace-ring span
         when tracing is armed) and the rank's wire goes dark in both
         directions. The fault-injection primitive of the self-healing
-        soak (bench --fault-gate, tests/test_resilience.py)."""
+        soak (tests/test_resilience.py)."""
         if self._rt:
             self._lib.accl_rt_kill(self._rt)
 

@@ -70,8 +70,8 @@ class TPUDevice(CCLODevice):
         self.compiler = ScheduleCompiler(mesh, axis_name)
         # Two-tier (inner_world, outer_world) shape for the hierarchical
         # compositions: DCNDevice sets it from its (ici, dcn) mesh; a
-        # flat mesh may declare a VIRTUAL factoring (the bench's
-        # 8-ranks-as-4x2 emulated world). None = flat — and even with a
+        # flat mesh may declare a VIRTUAL factoring (e.g. 8 ranks as
+        # an emulated 4x2 world). None = flat — and even with a
         # topology, hierarchical plans stay unreachable until the
         # HIER_ALLREDUCE_MIN_COUNT register is tuned on.
         self.hier_topology = hier_topology

@@ -281,8 +281,8 @@ def make_moe_train_step(cfg: MoEConfig, mesh: Mesh, lr: float = 1e-2):
 # ---------------------------------------------------------------------------
 
 # kernel-stream id the expert-FFN consumer registers under (any id in
-# 1..246 works; one well-known default keeps the bench, the dryrun and
-# the tests on the same endpoint)
+# 1..246 works; one well-known default keeps the dryrun and the tests
+# on the same endpoint)
 MOE_EXPERT_STREAM = 11
 
 
@@ -392,9 +392,9 @@ def run_moe_layer(accl, disp, mid, out, count: int, *,
     fused=False with `expert_fn` (make_expert_program) instead runs the
     fully EAGER descriptor-per-stage form — dispatch alltoall, the
     standalone expert program, combine alltoall: three dispatches, the
-    pre-fusion baseline the bench's moe_dispatch gate measures against
-    (intermediates stay on-device via from/to_device, so the baseline
-    pays the dispatch seams, not artificial host round trips).
+    pre-fusion baseline (intermediates stay on-device via
+    from/to_device, so the baseline pays the dispatch seams, not
+    artificial host round trips).
 
     `compress_dtype=DataType.int8` rides the blockwise-quantized wire on
     both legs explicitly; leaving it None defers to the device's

@@ -72,8 +72,8 @@ class _Slot:
 class DecodeServer:
     """Multiplex concurrent decode requests over one fused decode-step
     program (mode="fused", the production path) or its dispatch-per-
-    layer eager twin (mode="eager", the baseline the serve gate measures
-    the fusion win against). One instance owns its ACCL facade's decode
+    layer eager twin (mode="eager", the unfused baseline). One
+    instance owns its ACCL facade's decode
     buffers; all requests share them, one slot each.
 
     `model` is a decode model (`transformer.FlagshipDecode`,

@@ -63,7 +63,7 @@ class TransformerConfig:
 
 
 # The flagship dense transformer at its on-chip width, one v5e chip at
-# batch 8 x seq 1024: bench.py's train and decode lanes and chip_smoke.py.
+# batch 8 x seq 1024: chip_smoke.py's train and serve phases.
 FLAGSHIP_CONFIG = TransformerConfig(vocab=32768, d_model=1024, n_heads=16,
                                     n_kv_heads=4, n_layers=8, d_ff=4096,
                                     dtype="bfloat16")
@@ -698,7 +698,7 @@ def shard_params(params, cfg, mesh):
 # ---------------------------------------------------------------------------
 
 # kernel-stream id the train step's fwd+bwd consumer registers under
-# (one well-known default keeps bench, fuzz and tests on the endpoint)
+# (one well-known default keeps fuzz and tests on the endpoint)
 TRAIN_GRAD_STREAM = 21
 
 
@@ -852,7 +852,7 @@ def record_train_step(accl, cfg: TransformerConfig, tokens, targets, *,
     the steady-state SequenceProgram (`make_train_step_program`), and
     the same three descriptors issued eagerly are the serial
     dispatch->compute twin (`run_train_step_eager`) — bitwise-identical
-    at fp32, the measured A/B of bench --overlap-gate."""
+    at fp32."""
     if buffers is None:
         buffers = create_train_step_buffers(accl, cfg)
     pbuf, gbuf, ubuf, obuf = buffers

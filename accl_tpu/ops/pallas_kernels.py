@@ -84,8 +84,8 @@ def combine_pallas(a, b, op: str = "sum", interpret: bool | None = None,
     """Elementwise SUM/MAX over two flat buffers via Pallas (reduce_ops
     stream_add/stream_max analog, reduce_ops.cpp:31-73). float16 lanes
     route through XLA on real TPU (see _mosaic_rejects). block_rows /
-    lanes set the per-grid-step VMEM tile (default _BLOCK_ROWS x _LANES;
-    the bench sweeps both on-chip to pick the streaming-regime optimum)."""
+    lanes set the per-grid-step VMEM tile (default _BLOCK_ROWS x
+    _LANES)."""
     return _for_target(
         functools.partial(_combine, op=op, block_rows=block_rows,
                           lanes=lanes), a, b, interpret=interpret)

@@ -60,7 +60,8 @@ def _attend_group(q, k, v, *, axis_name: str, world: int, causal: bool,
     """One head group's full Ulysses round trip: re-shard to
     head-sharded, attend with full sequence visibility, re-shard back.
     Heads are independent in attention, so running the groups
-    separately is bitwise what one monolithic round trip computes."""
+    separately computes what one monolithic round trip computes, to
+    within a few ulps (see `ulysses_attention`)."""
     qg, kg, vg = (_seq_to_heads(t, axis_name, world, wire)
                   for t in (q, k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", qg, kg).astype(jnp.float32) * sm_scale
@@ -93,12 +94,16 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
     still divisible by the axis size) and every group runs its own
     in-alltoall -> attention -> out-alltoall chain. The groups are
     data-independent, so XLA overlaps group i's wire with group i+1's
-    matmuls — and because attention is per-head, the striped result is
-    BITWISE-identical to stripes=1 (pinned). stripes=2 is the classic
+    matmuls. Attention is per-head, so the striped result is
+    stripes=1's answer, but not bitwise: a group attends over fewer
+    heads, and XLA compiles the softmax and P.V product differently at
+    that shape. The two agree to within 4 ulps of the output's largest
+    magnitude (pinned; at most 1.5 ulps measured on the CPU mesh).
+    stripes=2 is the classic
     double buffer; pick the depth with timing.best_overlap_stripes
     when a calibration exists. serial=True order-barriers group i+1's
     inputs on group i's output — the serial dispatch->compute twin,
-    same values, measurable A/B."""
+    bitwise the striped values, measurable A/B."""
     world = lax.axis_size(axis_name)
     B, T, H, D = q.shape
     if H % world != 0:

@@ -151,8 +151,8 @@ class SequenceRequest(TPURequest):
         # was admitted into by ACCL.certify_concurrent, if any
         self.interference_cert: str | None = None
         # exactly one device dispatch happened for the whole batch — the
-        # observable inversion the sequence layer exists for (bench.py's
-        # sequence_fused_vs_eager row and the cache-hit test read this)
+        # observable inversion the sequence layer exists for (the
+        # sequence tests read this)
         self.num_dispatches = 1
 
 

@@ -37,12 +37,12 @@ deltas) tells a LOSSY link — frames arriving-but-damaged, a structured
 :class:`IntegrityFault`, no reconfiguration — from a genuinely DARK
 one, which alone walks the retry→exclude→replan path.
 
-Measured end to end by ``bench.py --fault-gate`` and ``--chaos-gate``
-(CI): a mid-stream rank death on the native emulated world recovers
-within the bounded retry+reconfigure budget with zero wrong answers
-(armed-deadline control <3% overhead over unarmed waits), and the
-seeded loss/corrupt/dup/reorder soak stays bitwise with zero false
-dead-rank escalations under <3% no-fault CRC+ack overhead.
+Checked end to end on the native emulated world by
+``tests/test_resilience.py`` (a mid-stream rank death recovers within
+the bounded retry+reconfigure budget, bitwise against the survivor
+oracle) and ``tests/test_fault_injection.py`` (the seeded
+loss/corrupt/dup/reorder fuzz stays bitwise with no call surfacing an
+error).
 """
 
 from .deadline import (  # noqa: F401

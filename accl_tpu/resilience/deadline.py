@@ -85,7 +85,7 @@ class DeadlineMissed:
     post_mortem: dict | None = None
 
     def verdict(self) -> dict[str, Any]:
-        """JSON-ready rendering (the fault-gate artifact / logs)."""
+        """JSON-ready rendering (logs)."""
         out: dict[str, Any] = {
             "kind": "deadline_missed",
             "op": self.op,
@@ -333,8 +333,8 @@ class NativeDeadlineGuard:
         from ..constants import ACCLError, ErrorCode
 
         pol = self.policy
-        # ONE cached lookup per wait: this is the armed hot path the
-        # fault gate's <3% control budget measures per dispatch
+        # ONE cached lookup per wait: this is the armed hot path, paid
+        # on every dispatch
         pred, dl = pol.predict_and_deadline(op, count, elem_bytes)
         t0 = time.perf_counter()
         try:

@@ -73,7 +73,7 @@ class IntegrityFault:
     post_mortem: dict | None = None
 
     def verdict(self) -> dict[str, Any]:
-        """JSON-ready rendering (the chaos-gate artifact / logs)."""
+        """JSON-ready rendering (logs)."""
         out: dict[str, Any] = {
             "kind": "integrity_fault",
             "op": self.op,

@@ -15,7 +15,7 @@ calibrated cost the admission control already priced the entry at):
 dispatch picks the eligible head with the smallest finish tag and
 advances the class's virtual time V to the dispatched entry's start
 tag. Long-run dispatched cost per backlogged tenant then tracks its
-weight share — the bench gate measures exactly that ratio. Across
+weight share (tests/test_scheduler.py pins the ratio). Across
 classes priority is STRICT: class 0 drains before class 1 sees the
 link; preemption happens at program boundaries because selection
 re-runs before every dispatch (an arriving class-0 entry wins the next

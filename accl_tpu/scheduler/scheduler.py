@@ -32,8 +32,8 @@ one facade with provable isolation instead of hope:
   in-flight group at its pick, itself included — a solo dispatch
   carries the singleton certificate). The id rides the dispatch span
   and request (`interference_cert`), so the flight recorder can name
-  the admitted set any interleaving belonged to, and the bench gate
-  can prove ZERO uncertified concurrent dispatches happened.
+  the admitted set any interleaving belonged to, and
+  `stats["uncertified_concurrent"]` proves none ran without one.
 
 * **Accountability.** Per-tenant series through the metrics registry
   (`accl_tenant_dispatch_seconds{tenant=...}` p50/p95/p99/p99.9, queue
@@ -121,7 +121,7 @@ class MultiTenantScheduler:
             "dispatches": 0,
             "concurrent_dispatches": 0,  # picked with >= 1 in flight
             "certified_concurrent": 0,   # ... under a clean group cert
-            "uncertified_concurrent": 0,  # must stay 0 (the gate pins it)
+            "uncertified_concurrent": 0,  # must stay 0 (tests pin it)
             "serialized_admissions": 0,
             "rejected_saturated": 0,
             "max_inflight": 0,
@@ -340,7 +340,7 @@ class MultiTenantScheduler:
                 self.stats["certified_concurrent"] += 1
             else:
                 # belt-and-braces: _eligible_locked makes this
-                # unreachable, but the gate pins the counter at 0 so a
+                # unreachable, but tests pin the counter at 0 so a
                 # future scheduling bug fails loudly, not silently
                 self.stats["uncertified_concurrent"] += 1
                 self._reg.counter(
@@ -522,7 +522,7 @@ class MultiTenantScheduler:
         return out
 
     def report(self) -> dict[str, Any]:
-        """The JSON block the bench gate and the artifact carry:
+        """A JSON-ready block:
         scheduler stats, per-tenant accounting, namespace
         disjointness, and the noisy-neighbor attribution."""
         with self._mu:

@@ -39,7 +39,7 @@ class Tenant:
     comm: Any = None  # per-tenant communicator handle (optional)
     # WFQ state: finish tag of this tenant's last enqueued entry
     finish_tag: float = 0.0
-    # accounting (the bench gate and the noisy-neighbor report read
+    # accounting (report() and the noisy-neighbor report read
     # these; the metrics registry carries the same numbers as series)
     submitted: int = 0
     dispatched: int = 0

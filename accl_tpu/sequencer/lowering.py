@@ -78,8 +78,7 @@ class ScheduleCompiler:
             # the serial dispatch->compute twin of a stripe-overlapped
             # allreduce plan (Plan.stripes > 1): order-only barriers
             # serialize the stripe chains, bitwise-identical to the
-            # overlapped form — the A/B baseline bench --overlap-gate
-            # measures against (same knob pattern as the pallas ring's
+            # overlapped form (same knob pattern as the pallas ring's
             # serialized baseline above)
             import os
 

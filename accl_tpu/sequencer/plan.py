@@ -291,7 +291,7 @@ def select_algorithm(
     # one point in the factored search space, and the schedule that
     # predicts faster wins the cell. No tiered entry (or no per-tier
     # calibration) keeps the old behavior bit-for-bit;
-    # tiered_synth_ok=False pins the composition (the bench/lint
+    # tiered_synth_ok=False pins the composition (the lint
     # twin-measurement escape, like select_wire's quantized_ok). Only
     # exact uncompressed unstreamed calls are eligible; per-tier
     # compression rides tier_wires/the plan's tier dtypes instead of

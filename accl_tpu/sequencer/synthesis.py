@@ -1379,8 +1379,8 @@ def search(op: Operation, world: int, link: Any, *,
     if tiers is not None and tier_links is None:
         raise SynthesisError(
             "tiered search needs tier_links (per-tier scoring "
-            "calibration): pass timing.TierLinks or run "
-            "bench.py --hier-gate to ship one")
+            "calibration): pass timing.TierLinks or commit a "
+            "link_tiers section to accl_log/timing_model.json")
     scored: list[tuple[SynthSpec, tuple[int, int],
                        dict[int, tuple[float, float]], float]] = []
     cands = (enumerate_tiered_candidates(world, tiers)
@@ -1549,7 +1549,7 @@ def export_entry(result: SearchResult,
 
 def shipped_link() -> Any:
     """LinkParams from the committed calibrated timing model — the same
-    link `ACCL.autotune`, `bench.py --check`, and `tools/accl_synth`
+    link `ACCL.autotune` and `tools/accl_synth`
     resolve (timing.emulator_link, the one resolution rule)."""
     from .timing import emulator_link
 
@@ -1566,7 +1566,8 @@ def shipped_link() -> Any:
 
 def shipped_tier_links() -> Any:
     """TierLinks from the committed calibrated timing model's
-    `link_tiers` section (written by bench.py --hier-gate) — the
+    `link_tiers` section (fitted on the CPU tier's emulated two-tier
+    world; its writer is retired) — the
     scoring calibration tiered library entries are verified under.
     Raises loudly when absent: a library with tiered entries and no
     shipped per-tier calibration cannot be re-validated."""
@@ -1576,8 +1577,8 @@ def shipped_tier_links() -> Any:
     if tiers is None:
         raise SynthesisError(
             "the shipped timing model carries no link_tiers (needed to "
-            "re-validate tiered library windows) — run "
-            "bench.py --hier-gate to calibrate the two-tier world")
+            "re-validate tiered library windows) — commit a "
+            "link_tiers section to accl_log/timing_model.json")
     return tiers
 
 

@@ -148,7 +148,7 @@ def emulator_link(model: dict[str, Any]) -> LinkParams:
     aggregate and critical-path shapes coincide, so its alpha/beta are
     genuine per-message/per-byte host costs), with fallback to the
     legacy single-"link" key. The ONE resolution rule shared by
-    ACCL.autotune, bench.py --check, and tools/accl_synth — a schema
+    ACCL.autotune and tools/accl_synth — a schema
     change lands here or nowhere."""
     lk = (model.get("link_per_collective", {}).get("bcast")
           or model.get("link"))
@@ -354,7 +354,7 @@ def coefficients_aggregate(
 ) -> tuple[float, float]:
     """(messages, bytes) SUMMED OVER ALL RANKS — the cost shape a
     serialized host actually pays. The emulator runs its whole world on
-    one CI core (accl_log/REPORT.md r5 analysis), so wall time tracks
+    one CI core (the round-5 emulator analysis), so wall time tracks
     the total work moved through the machine, not the critical path:
     fitting this shape per collective put the fitted beta at the
     measured ~1.4-2 GB/s transport rate and the median error under

@@ -34,15 +34,15 @@ tracer's observer seam — never at trace drain:
     (errors.notify_sticky_retcode) without tracing ever having been
     enabled.
 
-Entry points: bench.py --trace emits the full trace + residual section
-and bench.py --obs-gate proves the sentinel + overhead claims;
-tools/accl_trace.py exports/validates/selftests standalone (--metrics
-replays a trace through the registry). Host tracing is off by default
+Entry points: tools/accl_trace.py exports/validates/selftests
+standalone (--metrics replays a trace through the registry); the
+benchmark's traced runs read the spans of the eager call path
+(benchmark/program_spans.py). Host tracing is off by default
 (ACCL_TELEMETRY=1 or telemetry.enable()); the observability layer is
 ON by default (ACCL_OBS=0 opts out) and rides the same emission seam.
-The fully-disabled path is one predicate per site, gated <1% on the
-bench smoke path. See docs/observability.md for the schema table and
-the calibration-loop walkthrough.
+The fully-disabled path is one predicate per site. See
+docs/observability.md for the schema table and the calibration-loop
+walkthrough.
 """
 
 import os as _os
@@ -103,14 +103,10 @@ def enable_observability() -> None:
 
 
 def disable_observability() -> None:
-    """Detach metrics + flight recorder (the 'nobody watching' state
-    the <1% disabled-overhead gate measures)."""
+    """Detach metrics + flight recorder (the 'nobody watching'
+    state)."""
     metrics.uninstall(get_tracer())
     recorder.uninstall(get_tracer())
-
-
-def observability_enabled() -> bool:
-    return recorder.armed()
 
 
 # always-on by default: the metrics registry and flight recorder are

@@ -1,7 +1,7 @@
 """Trace export: event-schema validation, Chrome trace-event JSON, and
 the predicted-vs-measured residual table.
 
-The trace document (tracer.Tracer.to_trace / accl_log/trace.json) is the
+The trace document (tracer.Tracer.to_trace, written by write_trace) is the
 one exchange format; this module turns it into
 
   - Chrome trace-event JSON (Perfetto / chrome://tracing loadable): one

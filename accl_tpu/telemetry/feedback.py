@@ -60,9 +60,8 @@ def _mtime_ns(p: pathlib.Path) -> int | None:
 
 def _model_cache_get(p: pathlib.Path, kind: str, load):
     """Freshness-checked cache for loaded timing-model sections. A
-    `timing_model.json` OVERWRITTEN later in the same process (bench
-    gates rewrite link_tiers / compute_fit; a live refitter will
-    rewrite the link) bumps the file's mtime and is re-read within
+    `timing_model.json` OVERWRITTEN later in the same process (a
+    refitter rewriting a section) bumps the file's mtime and is re-read within
     _STAT_TTL_S, where the old per-path cache served the stale model
     for the rest of the process. A missing file caches its negative
     result under mtime None, so the file appearing later is still
@@ -166,8 +165,8 @@ def calibrate_from_trace(trace: dict, tier: str | None = None) -> LinkParams:
 def calibrate_tiers_from_trace(trace: dict) -> TierLinks:
     """The per-tier form of calibrate_from_trace: each tier of a
     two-tier world refit INDEPENDENTLY from its own tier-tagged spans
-    (args["tier"] == "inner" / "outer" — the emulated 2-tier bench
-    world tags inner-POE and outer-TCP calls at drain time). This is
+    (args["tier"] == "inner" / "outer" — an emulated 2-tier world
+    tags inner-POE and outer-TCP calls at drain time). This is
     what makes the hierarchical predictions honest: the DCN link's
     alpha/beta are fit from DCN measurements only, never averaged with
     ICI's."""
@@ -178,10 +177,10 @@ def calibrate_tiers_from_trace(trace: dict) -> TierLinks:
 def default_tier_links(path=None) -> TierLinks | None:
     """The shipped per-tier calibration: the timing model document's
     `link_tiers` section ({"inner": {alpha_us, beta_gbps}, "outer":
-    {...}}, written by bench.py --hier-gate's per-tier refit). None
-    when the model carries no tier fit — callers (autotune, stripe
-    selection) must then leave hierarchical selection off rather than
-    invent a slow-tier model."""
+    {...}}, a per-tier refit from the CPU tier's emulated two-tier
+    world). None when the model carries no tier fit — callers
+    (autotune, stripe selection) must then leave hierarchical selection
+    off rather than invent a slow-tier model."""
     p = pathlib.Path(path) if path else _MODEL_PATH
     # negative results cached too (per mtime): this sits on the
     # per-call plan selection path (an in-window select_algorithm with
@@ -208,10 +207,10 @@ def compute_samples(trace: dict) -> list[tuple[float, float]]:
     """(operand_bytes, measured_seconds) samples from every span that
     carries a `compute_bytes` arg and a positive measurement — the
     busy-core term of the overlap pipeline (timing.ComputeFit), fitted
-    from spans exactly like the link is fitted from hop spans. The
-    overlap gate emits these by timing the train step's compute stage
-    at two model sizes and tagging each span with the gradient bytes
-    that stage materializes."""
+    from spans exactly like the link is fitted from hop spans. A
+    calibration sweep emits these by timing the train step's compute
+    stage at two model sizes and tagging each span with the gradient
+    bytes that stage materializes."""
     samples = []
     for sp in trace.get("spans", []):
         if not isinstance(sp, dict):
@@ -239,14 +238,14 @@ def calibrate_compute_from_trace(trace: dict) -> ComputeFit:
         raise ValueError(
             f"trace has {len(samples)} compute span(s); need >= 2 "
             "(spans with args.compute_bytes at distinct sizes — the "
-            "overlap gate's compute-calibration sweep emits them)")
+            "train step's compute stage timed at two model sizes)")
     return calibrate_compute(samples)
 
 
 def default_compute_fit(path=None) -> ComputeFit | None:
     """The shipped compute-term calibration: the timing model
-    document's `compute_fit` section ({alpha_us, grad_gbps}, written
-    by bench.py --overlap-gate's refit). None when no fit is committed
+    document's `compute_fit` section ({alpha_us, grad_gbps}, refitted
+    from CPU-mesh train-step spans). None when no fit is committed
     — callers (autotune, overlap stripe selection) must then leave the
     overlap register off rather than invent a compute model. Results
     are cached per (path, mtime) — this sits on the per-call plan
@@ -277,8 +276,9 @@ def _rel_errs(trace: dict, link: LinkParams) -> list[float]:
 def residual_improvement(trace: dict,
                          default: LinkParams | None = None) -> dict:
     """Median relative residual under the shipped default link vs under
-    the trace's own refit, over the same calibratable spans. The bench
-    --trace gate requires refit <= default: if refitting on the very
+    the trace's own refit, over the same calibratable spans.
+    `tools/accl_trace.py --selftest` requires refit <= default on the
+    golden trace: if refitting on the very
     measurements cannot beat the shipped constants, the feedback loop
     is broken (or the cost shapes regressed)."""
     if default is None:
@@ -308,7 +308,7 @@ def autotune_from_trace(accl, trace: dict, **autotune_kw):
 
 
 def residual_report(trace: dict) -> dict:
-    """The residual section bench.py --trace embeds in its JSON: the
+    """The residual section of a trace report: the
     span-level residual summary (spans carrying predicted_s) plus the
     default-vs-refit improvement over the calibratable samples."""
     rows = residual_rows(trace)

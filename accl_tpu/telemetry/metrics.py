@@ -29,10 +29,7 @@ when nobody ever exports a trace:
 Everything here is bounded: histogram windows, sentinel windows, and
 the label space (collectives x algorithms x protocols x worlds) are all
 small by construction, so an always-on registry cannot grow without
-limit in a long-lived process. ``bench.py --obs-gate`` measures the
-per-event observe cost against the per-call median latency (< 3% on
-the traced hot path) and proves the sentinel flags an injected
-WAN-shaper regime change while staying quiet on a stable control run.
+limit in a long-lived process.
 """
 
 from __future__ import annotations
@@ -75,7 +72,7 @@ def _label_value_cap() -> int:
 DEFAULT_HISTOGRAM_WINDOW = 512
 # p99.9 rides the same 512-sample window as the rest: nearest-rank over
 # 512 samples makes it the window maximum until ~1000 samples would fit,
-# which is exactly the honest tail readout an interactive-serving gate
+# which is exactly the honest tail readout an interactive server
 # wants (the worst step seen in the last window, stabilizing as windows
 # grow) — not a fabricated interpolation past the data
 QUANTILES = (0.5, 0.95, 0.99, 0.999)
@@ -490,8 +487,8 @@ class DriftSentinel:
         return out
 
     def report(self) -> dict[str, Any]:
-        """The JSON block bench --obs-gate / --check and the trace meta
-        carry: verdict + flags + straggler attribution."""
+        """The JSON block the trace meta carries: verdict + flags +
+        straggler attribution."""
         return {
             "window": self.window,
             "min_samples": self.min_samples,

@@ -6,7 +6,7 @@ after the fact (SURVEY.md L2/L4; the native runtime's trace ring is that
 posture rebuilt, runtime.cpp record_span). This module is the HOST side
 of the same contract: a thread-safe, bounded, drop-oldest ring of span
 events that the facade, the sequence machinery, and the device backends
-emit into, and that tools/accl_trace.py / bench.py --trace export as
+emit into, and that tools/accl_trace.py exports as
 Chrome trace-event JSON (telemetry.export).
 
 One stable event schema (SPAN v1) spans every emitter:
@@ -27,7 +27,7 @@ One stable event schema (SPAN v1) spans every emitter:
 
 Tracing is OFF by default and costs one predicate per instrumented site
 when off (`span()` returns a shared no-op object before any argument
-handling): the bench smoke path gates that disabled overhead under 1%.
+handling).
 Enable with ACCL_TELEMETRY=1 in the environment or telemetry.enable().
 
 The tracer is also the ONE emission seam of the always-on observability

@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache.
 
-Called by the entry scripts (chip_smoke.py, bench.py, examples/) before
+Called by the entry scripts (chip_smoke.py, examples/) before
 their first compile, never on import of the library: a library that turns
 on a cache for whoever imports it writes files its users did not ask for.
 """

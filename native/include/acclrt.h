@@ -143,7 +143,7 @@ enum accl_rt_stat2 {
   /* vectored-wire transmit shape (the zero-copy scatter-gather path):
    * syscalls issued for frame transmit, and frames that shipped inside
    * a multi-frame writev/sendmmsg batch. syscalls/tx_frames is the
-   * per-frame syscall ratio `bench --wire-gate` budgets; both stay 0
+   * per-frame syscall ratio of the vectored wire; both stay 0
    * on the in-process POE (no syscalls to count). */
   ACCL_RT_STAT2_TX_SYSCALLS = 21,
   ACCL_RT_STAT2_TX_BATCHED = 22,

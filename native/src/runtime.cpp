@@ -427,7 +427,7 @@ struct accl_rt : public acclw::PoeSink {
   uint32_t n_lanes = 1;  // ACCL_INIT_CONST
   uint64_t lane_bulk_bytes = 64ull << 10;  // ACCL_INIT_CONST; ACCL_RT_LANE_BULK_BYTES
   bool legacy_wire = false;  // ACCL_INIT_CONST; ACCL_RT_WIRE_LEGACY: per-frame-syscall
-                             // cost model, batching off (bench A/B)
+                             // cost model, batching off (the A/B baseline)
   bool tx_batch_on = false;  // ACCL_INIT_CONST; computed at create: vectored batching
                              // armed (off under chaos/WAN/legacy/local
                              // — those paths need per-frame emission)
@@ -690,7 +690,7 @@ struct accl_rt : public acclw::PoeSink {
   //     (framing preserved for the peer's tx path) and discarded. Peers
   //     observe exactly what a dead host produces: silence, surfacing as
   //     their own recv deadlines. accl_rt_kill() is the programmatic
-  //     form (the fault-gate soak kills a rank mid-stream).
+  //     form (the resilience tests kill a rank mid-stream).
   std::atomic<bool> killed{false};
   int kill_after_calls = -1;  // ACCL_ROLE_ONLY(seq); sequencer-thread only; -1 = unarmed
 
@@ -708,7 +708,7 @@ struct accl_rt : public acclw::PoeSink {
   // serialize like a real wire) before entering the kernel, turning
   // loopback sockets into an emulated slow cross-slice (DCN) tier.
   // Read at create, so one process can hold differently-shaped worlds:
-  // the bench's emulated 2-tier world is unshaped local-POE pods
+  // an emulated 2-tier world is unshaped local-POE pods
   // (fast ICI tier) beside shaped TCP groups (slow DCN tier). The
   // local POE is never shaped — it IS the fast tier.
   uint32_t wan_alpha_us = 0;  // ACCL_INIT_CONST
@@ -3545,8 +3545,8 @@ accl_rt_t *accl_rt_create_ex(uint32_t world, uint32_t rank,
                    rt->fault_pct_armed);
   // ----- wire shape: legacy cost model, lanes, TX batching ----------------
   // ACCL_RT_WIRE_LEGACY=1: pre-vectored transmit (per-frame syscalls,
-  // coalescing copies) — the A/B baseline `bench --wire-gate` measures
-  // the scatter-gather path against. Legacy implies the single-lane
+  // coalescing copies) — the A/B baseline for the scatter-gather path.
+  // Legacy implies the single-lane
   // bit-identical wire.
   if (const char *s = getenv("ACCL_RT_WIRE_LEGACY"))
     rt->legacy_wire = atoi(s) != 0;
@@ -3785,7 +3785,7 @@ void accl_rt_release(accl_rt_t *rt, int64_t handle) {
 uint32_t accl_rt_read(accl_rt_t *rt, uint32_t addr) { return rt->rd(addr); }
 
 // Permanently wedge a rank (see the ACCL_RT_FAULT_KILL_RANK lever): the
-// programmatic kill the fault-gate soak fires mid-stream. Idempotent.
+// programmatic kill the resilience tests fire mid-stream. Idempotent.
 void accl_rt_kill(accl_rt_t *rt) { rt->wedge(); }
 
 // Reconfiguration fence (see accl_rt::flush_rx): drain stale frames of

@@ -7,8 +7,7 @@
 // borrowed in place — no coalescing copy anywhere on the vectored
 // path (the session asserts payload_copies() == 0). The pre-vectored
 // cost model (per-frame syscalls, datagram staging copies) survives
-// behind ACCL_RT_WIRE_LEGACY as the A/B baseline `bench --wire-gate`
-// measures against.
+// behind ACCL_RT_WIRE_LEGACY as the A/B baseline.
 //
 // SEAM RULE: this file must not include reliability.h — the transport
 // carries already-built frames and knows nothing about CRC, retransmit
@@ -483,7 +482,7 @@ class TcpPoe final : public Poe {
     // legacy cost model: one recv per header, one per payload; the
     // vectored path batches — a single large recv drains many frames
     // into the per-link buffer (the rx half of the syscalls-per-frame
-    // win the wire gate measures)
+    // saving)
     RxBuf rb(cfg_.legacy_wire ? 0 : RX_BUF_CAP);
     while (!stop_.load()) {
       MsgHeader h;

@@ -69,7 +69,7 @@ struct PoeConfig {
   uint32_t lanes = 1;               // per-peer lanes (TCP only, <= WIRE_MAX_LANES)
   // ACCL_RT_WIRE_LEGACY=1: the pre-vectored cost model — per-frame
   // syscalls, payload coalescing copies, no batching. Kept as the A/B
-  // baseline `bench --wire-gate` measures the vectored path against.
+  // baseline for the vectored path (tests/test_fault_injection.py).
   bool legacy_wire = false;
   // Optional per-frame WAN charge (the emulated slow-tier shaper): when
   // set, the POE charges it per frame under the same per-(dst, lane)

@@ -245,14 +245,27 @@ def _lower_allreduce(mesh, world, count, wire):
     return ScheduleCompiler(mesh, use_pallas_ring=False).lower(opts, plan)
 
 
+def _jaxpr_ppermute_bytes(jaxpr) -> int:
+    """Sum the operand bytes of every ppermute equation in a (closed)
+    jaxpr: the bytes each rank puts on the wire for one execution of
+    the traced program (every cross-rank hop in the schedule layer is a
+    ppermute)."""
+    from jax.extend import core as jcore
+
+    from accl_tpu.analysis.protocol import iter_ppermute_eqns
+
+    return sum(v.aval.size * v.aval.dtype.itemsize
+               for eqn in iter_ppermute_eqns(jaxpr)
+               for v in eqn.invars
+               if not isinstance(v, jcore.Literal))
+
+
 def test_wire_bytes_16mib_reduction(mesh8):
     """The acceptance gate's static form: at a 16 MiB fp32 payload on
     the 8-device mesh, the TOTAL ppermute operand bytes of the lowered
     int8-wire ring allreduce must sit >= 1.9x below the fp32 program's
     (measured from the traced jaxpr — every cross-rank hop is a
     ppermute, scale side-channels included)."""
-    from bench import _jaxpr_ppermute_bytes
-
     world, count = 8, (16 * 1024 * 1024) // 4
     arg = jax.ShapeDtypeStruct((world, count), np.float32)
     b_fp32 = _jaxpr_ppermute_bytes(jax.make_jaxpr(

@@ -574,6 +574,42 @@ def test_stats2_versioned_counter_surface():
     assert all(isinstance(v, int) for v in ws.values())
 
 
+@pytest.mark.parametrize("wire", ["vectored", "legacy"])
+def test_tcp_transmit_shape(fault_env, wire):
+    """A multi-frame eager message on the TCP wire lands bitwise either
+    way, and the sender's stats2 counters show which transmit path ran:
+    the vectored wire (default) gathers many frames into one writev
+    (tx_batched > 0, fewer syscalls than frames); ACCL_RT_WIRE_LEGACY=1
+    sends frame by frame and batches nothing."""
+    fault_env(ACCL_RT_RELY=0)
+    if wire == "legacy":
+        fault_env(ACCL_RT_WIRE_LEGACY=1)
+    n = (256 << 10) // 4
+    msg = RNG.integers(-100, 100, size=n).astype(np.int32)
+    w = EmuWorld(2, transport="tcp", max_eager=1 << 20, rx_buf_bytes=4096)
+    try:
+        def body(rank, i):
+            if i == 0:
+                rank.send(msg.copy(), n, dst=1, tag=21)
+                return None
+            out = np.zeros(n, np.int32)
+            rank.recv(out, n, src=0, tag=21)
+            return out
+
+        res = w.run(body)
+        ws = w.ranks[0].wire_stats()
+    finally:
+        w.close()
+    np.testing.assert_array_equal(res[1], msg)
+    assert ws["tx_frames"] > 1
+    if wire == "vectored":
+        assert ws["tx_batched"] > 0
+        assert ws["tx_syscalls"] < ws["tx_frames"]
+    else:
+        assert ws["tx_batched"] == 0
+        assert ws["tx_syscalls"] >= ws["tx_frames"]
+
+
 def test_corrupt_frames_counted_dropped_and_repaired(fault_env):
     """A heavy corrupt rate: every flipped frame must be caught by the
     CRC (counted, dropped, never landed) and repaired by the nack

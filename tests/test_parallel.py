@@ -320,12 +320,17 @@ def test_ulysses_quantized_wire_within_bound():
 
 
 @pytest.mark.parametrize("stripes", [2, 4])
-def test_ulysses_striped_bitwise(stripes):
+def test_ulysses_striped_matches_monolithic(stripes):
     """Double-buffered Ulysses: splitting the two re-sharding
     all-to-alls into head-group stripes (overlapped against the
-    attention matmuls) is BITWISE-identical to the monolithic round
-    trip — attention is per-head, alltoall is pure routing — and the
-    serial twin (order-barriered groups) matches too."""
+    attention matmuls) computes the monolithic round trip's answer.
+    Attention is per-head and alltoall is pure routing, but each stripe
+    attends over fewer heads, and XLA compiles the softmax and the P.V
+    product differently at that shape: the two agree to within 4 ulps
+    of the output's largest magnitude, not bitwise (on the CPU mesh
+    ~60% of elements differ, by at most 1.5 ulps). The serial twin
+    (order-barriered groups) is the same shapes in another order, so it
+    is bitwise the striped result."""
     world = 4
     B, T, H, D = 2, 8, 4 * stripes, 16
     mesh = Mesh(np.array(jax.devices()[:world]), ("sp",))
@@ -342,8 +347,12 @@ def test_ulysses_striped_bitwise(stripes):
         return np.asarray(f(q, k, v))
 
     base = run(1)
-    np.testing.assert_array_equal(base, run(stripes))
-    np.testing.assert_array_equal(base, run(stripes, serial=True))
+    ulps = 4 * np.spacing(np.abs(base).max())
+    striped = run(stripes)
+    serial = run(stripes, serial=True)
+    np.testing.assert_allclose(striped, base, rtol=0, atol=ulps)
+    np.testing.assert_allclose(serial, base, rtol=0, atol=ulps)
+    np.testing.assert_array_equal(serial, striped)
 
 
 def test_ulysses_striped_jaxpr_interleaves_compute():

@@ -699,8 +699,8 @@ def test_kill_fuzz_recovery_certified_and_bitwise(seed):
         # attempts): survivors stay in lockstep, so every frame a
         # survivor sends lands inside its peers' live wedged calls and
         # is consumed — the links between survivors are clean when the
-        # recovery communicator starts (the drain discipline the
-        # fault-gate soak uses too).
+        # recovery communicator starts (the drain discipline a
+        # recovery needs).
         w.ranks[victim].kill()
         action = None
         last_misses: dict[int, DeadlineMissed] = {}

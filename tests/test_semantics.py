@@ -23,6 +23,7 @@ Pins the analysis/semantics.py + analysis/hopdag.py contract:
 import json
 import pathlib
 import random
+import zlib
 
 import numpy as np
 import pytest
@@ -508,7 +509,7 @@ def test_certifier_vs_execution_fuzz(family):
     quantized = wire == DataType.int8
     mismatches = []
     for seed in range(_SEEDS):
-        rng = np.random.default_rng(hash((family, seed)) & 0xFFFFFFFF)
+        rng = np.random.default_rng([zlib.crc32(family.encode()), seed])
         pyrng = random.Random(seed * 7919 + len(family))
         world = int(rng.choice(worlds))
         count = int(rng.choice(counts))

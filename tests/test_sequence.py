@@ -349,7 +349,7 @@ def test_overlap_striped_sequence_jaxpr_structure():
     RS+AG ring chains (S * 2*(world-1) ppermutes), and the serialized
     twin (overlap_serialize) threads S-1 order-only barriers between
     them while keeping the identical wire structure — the lowering
-    seam bench --overlap-gate A/Bs."""
+    seam the serial twin rides."""
     import jax
 
     from accl_tpu.analysis.protocol import iter_ppermute_eqns

@@ -34,6 +34,7 @@ from accl_tpu.models import transformer as trf
 from accl_tpu.parallel import make_mesh
 from accl_tpu.sequencer import synthesis
 from accl_tpu.sequencer.plan import Algorithm, select_algorithm
+from accl_tpu.telemetry.metrics import MetricsRegistry
 
 CFG = trf.TransformerConfig(vocab=64, d_model=32, n_heads=4, n_kv_heads=2,
                             n_layers=2, d_ff=64)
@@ -278,10 +279,11 @@ def test_batched_equals_sequential_ragged_join_leave():
                                           int(rng.integers(1, 5)))))
                for _ in range(5)]
 
-    def run(mode, sequential):
+    def run(mode, sequential, registry=None):
         srv = serve.DecodeServer(ACCL(_mesh()),
                                  trf.FlagshipDecode(CFG, params_np),
-                                 batch=3, max_len=T, mode=mode)
+                                 batch=3, max_len=T, mode=mode,
+                                 registry=registry)
         if sequential:
             outs = []
             for p in prompts:
@@ -289,7 +291,15 @@ def test_batched_equals_sequential_ragged_join_leave():
             return outs
         return serve.generate(srv, prompts, 4)
 
-    batched = run("fused", sequential=False)
+    registry = MetricsRegistry()
+    batched = run("fused", sequential=False, registry=registry)
+    # the server reports every step's latency and every generated
+    # token to its registry
+    snap = registry.snapshot()
+    (step_row,) = snap["histograms"]["accl_serve_step_seconds"]
+    assert step_row["count"] > 0
+    (tok_row,) = snap["counters"]["accl_serve_tokens_total"]
+    assert tok_row["value"] == 4 * len(prompts)
     assert batched == run("fused", sequential=True), \
         "batched != sequential (join/leave churn leaked between slots)"
     assert batched == run("eager", sequential=False), \

@@ -3,11 +3,11 @@ the numpy oracle through hopdag.execute), library round trips, the
 certify gate's reject path, and the select_algorithm crossovers that
 make synthesized schedules first-class algorithms.
 
-The measured-speedup claim itself is enforced by `bench.py --check`
-against BASELINE_BENCH.json (CI); here the PREDICTED side of the
-acceptance bar is pinned (the synthesized entry beats the whole
-hand-written zoo on its winning cell under the shipped link) plus the
-structural properties the library rests on.
+The selection registers are fitted on the CPU tier and default to
+off, so no chip measurement stands behind a synthesized entry yet;
+here the PREDICTED side is pinned (the synthesized entry beats the
+whole hand-written zoo on its winning cell under the shipped link)
+plus the structural properties the library rests on.
 """
 
 import dataclasses
@@ -415,8 +415,8 @@ def test_crossovers_set_registers_and_selection_follows():
 def test_predicted_win_beats_whole_hand_written_zoo():
     """The predicted half of the acceptance bar: at the winning cell
     the synthesized schedule beats EVERY hand-written algorithm under
-    the shipped link (the measured half is bench.py --check's gate
-    against BASELINE_BENCH.json)."""
+    the shipped link (a measured half needs a chip cell the entry
+    serves)."""
     count = 1024  # 4 KB fp32, world 8
     key = synthesis.select_entry(Operation.allreduce, 8, 4096)
     assert key == "allreduce_w8_exchange_d1_2_4"
@@ -676,34 +676,6 @@ def test_select_entry_tiers_filter_and_crossover_exclusion():
               if e.spec.op == "allreduce" and e.spec.world == 16
               and not e.spec.wire and not e.spec.tiers]
     assert flat16, "flat w16 allreduce entries missing"
-
-
-# ---------------------------------------------------------------------------
-# Baseline table sanity (the bench --check contract)
-# ---------------------------------------------------------------------------
-
-
-def test_baseline_bench_table_committed_and_well_formed():
-    doc = json.loads((REPO / "BASELINE_BENCH.json").read_text())
-    assert doc["schema"] == 1
-    assert doc["sections"], "baseline table has no sections"
-    names = set(doc["sections"])
-    # gates whose measured floor was deliberately re-baselined below
-    # 1.0 carry a reviewed arbitration verdict in the refit record (the
-    # synth_tier cell: predicted win stands, measured wall clock on the
-    # CPU tier is dispatch-overhead-bound) — every other gate remains a
-    # strict speedup gate
-    arbitrated = {rec["gate"]
-                  for key, rec in doc.get("refit", {}).items()
-                  if key.endswith("_arbitration") and isinstance(rec, dict)}
-    for gate in doc["gates"]:
-        assert gate["fast"] in names and gate["slow"] in names
-        if gate["name"] in arbitrated:
-            assert 0 < gate["min_ratio"] < 1.0
-        else:
-            assert gate["min_ratio"] >= 1.0
-    # the headline gate: the synthesized allreduce cell is enforced
-    assert any("synth_allreduce" in g["name"] for g in doc["gates"])
 
 
 def test_export_prunes_stale_in_scope_entries(tmp_path, monkeypatch):

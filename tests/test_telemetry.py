@@ -882,8 +882,8 @@ def test_default_tier_links_reads_link_tiers(tmp_path):
     bare = tmp_path / "bare.json"
     bare.write_text(json.dumps({"link": {"alpha_us": 1, "beta_gbps": 1}}))
     assert default_tier_links(bare) is None
-    # and the COMMITTED model must carry the tier fit (bench --check's
-    # hier cell depends on it; regenerated by bench.py --hier-gate)
+    # and the COMMITTED model must carry the tier fit (the hier
+    # register's crossover reads it)
     assert default_tier_links() is not None
 
 
@@ -925,8 +925,8 @@ def test_default_link_cache_invalidates_on_refit_overwrite(tmp_path,
     assert default_tier_links(p) is None  # negative result, cached
     assert default_compute_fit(p) is None
 
-    # a later refit overwrites the file (bench gates do exactly this
-    # for link_tiers / compute_fit; a live refitter will for the link)
+    # a later refit overwrites the file (tools/timing_model.py does
+    # this for the link; a live refitter will for every section)
     p.write_text(json.dumps({
         "link": {"alpha_us": 50.0, "beta_gbps": 2.0},
         "link_tiers": {

@@ -36,7 +36,7 @@ def _ring_program(kernel_fn, world):
 
 def test_combine_and_cast_execute_on_chip():
     """The reduce_ops / hp_compression lanes execute (not just compile)
-    on the attached chip — the single-chip slice of the bench sweep."""
+    on the attached chip."""
     from accl_tpu.ops.pallas_kernels import cast_pallas, combine_pallas
 
     rng = np.random.default_rng(0)

@@ -583,7 +583,7 @@ def run_schedules(deep: bool = False, sample: int = 0,
                                inner=LinkParams(2e-6, 2e9),
                                outer=LinkParams(30e-6, 0.25e9)))
         if synth_tier is not None:
-            # a WAN-class outer link (the hier-gate's shaped regime):
+            # a WAN-class outer link (a shaped slow tier):
             # per-message latency on the slow tier dominates, which is
             # exactly where the log-step tiered entries displace the
             # striped composition in the arbitration

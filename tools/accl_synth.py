@@ -112,7 +112,7 @@ def load_tier_links(args):
     if tiers is None:
         raise SystemExit(
             f"{args.timing_model} carries no link_tiers (needed to "
-            "score tiered candidates) — run bench.py --hier-gate")
+            "score tiered candidates)")
     return tiers
 
 

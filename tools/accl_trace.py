@@ -2,7 +2,8 @@
 """Trace exporter / validator CLI for the telemetry subsystem.
 
 Thin client of accl_tpu.telemetry: takes a SPAN v1 trace document
-(bench.py --trace writes accl_log/trace.json) and
+(Tracer.to_trace + telemetry.write_trace; default: the committed golden
+trace) and
 
   --validate            check it against the jsonschema event contract
                         (telemetry.export.EVENT_SCHEMA)
@@ -382,8 +383,7 @@ def cmd_selftest() -> int:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("trace", nargs="?",
-                    default=str(REPO / "accl_log" / "trace.json"))
+    ap.add_argument("trace", nargs="?", default=str(GOLDEN))
     ap.add_argument("--validate", action="store_true")
     ap.add_argument("--chrome", metavar="OUT")
     ap.add_argument("--residuals", action="store_true")

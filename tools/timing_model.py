@@ -64,8 +64,9 @@ def load_rows(path: pathlib.Path, default_world: int):
 
 
 def tpu_tier(profile: pathlib.Path) -> dict | None:
-    """Second calibration tier from an on-chip profile (bench.py ->
-    accl_log/profile.csv, absent until a chip run writes one): the
+    """Second calibration tier from an on-chip profile
+    (accl_log/profile.csv: Test,Bytes,Seconds rows; absent until a chip
+    run writes one): the
     reference calibrates its simulator against silicon the same way
     (cycles x 4ns, xrtdevice.cpp:248). Measured quantities only:
 
