@@ -279,7 +279,11 @@ def seeded_rows(accl, key, layer: int, name: str, shape: tuple, axis,
 class MLADecodeDims:
     """Flat-buffer geometry of the fused decode step: every layer's state
     is [x (B*D) | pos (B) | latent cache (B*T*cache_width)], replicated
-    over the ranks; xp and logits are n_out wide."""
+    over the ranks; xp and logits are n_out wide. The cache starts at
+    `cache_base` and the state is `n_state` long, both rounded up to
+    `_STATE_ALIGN` numbers: on a TPU a flat (n,) array and the program's
+    (1, n) rank row then share one tiled layout, so the state passes
+    between them without a relayout."""
 
     batch: int
     max_len: int
@@ -290,8 +294,17 @@ class MLADecodeDims:
     ff_local: int
     shared_local: int
     cache_width: int
+    cache_base: int
     n_state: int
     n_out: int
+
+
+# the TPU's tile of a flat fp32 array, in numbers
+_STATE_ALIGN = 1024
+
+
+def _align(n: int) -> int:
+    return -(-n // _STATE_ALIGN) * _STATE_ALIGN
 
 
 def decode_dims(cfg: DeepSeekV2Config, world: int, batch: int,
@@ -304,6 +317,7 @@ def decode_dims(cfg: DeepSeekV2Config, world: int, batch: int,
             raise ValueError(
                 f"decode facade world {world} must divide {name}={dim}")
     D = cfg.hidden_size
+    base = _align(batch * D + batch)
     return MLADecodeDims(
         batch=batch, max_len=max_len, d_model=D, vocab=cfg.vocab_size,
         heads_local=cfg.num_attention_heads // world,
@@ -311,7 +325,8 @@ def decode_dims(cfg: DeepSeekV2Config, world: int, batch: int,
         ff_local=cfg.intermediate_size // world,
         shared_local=cfg.shared_width // world,
         cache_width=cfg.cache_width,
-        n_state=batch * D + batch + batch * max_len * cfg.cache_width,
+        cache_base=base,
+        n_state=_align(base + batch * max_len * cfg.cache_width),
         n_out=max(batch * cfg.vocab_size, batch * D + batch),
     )
 
@@ -475,25 +490,29 @@ def make_attn_consumer(cfg: DeepSeekV2Config, dims: MLADecodeDims,
     """Layer attention over the rank's flat state [x, pos, cache] with
     the layer's attention weights as operands: the new latent row of
     each slot goes into the cache at its position, and the step lands
-    [o_partial, pos, cache] back in the state."""
+    [o_partial, pos, cache] back in the state. Only the o prefix and
+    the B new rows are written, each in place, so the rest of the
+    state is never rebuilt."""
     B, D, T, W = dims.batch, dims.d_model, dims.max_len, dims.cache_width
+    base = dims.cache_base
     names = _names(_attn_weights(cfg))
 
     def consumer(state, *rows):
         x = state[:B * D].reshape(B, D)
         pos = state[B * D:B * D + B].astype(jnp.int32)
-        cache = state[B * D + B:].reshape(B, T, W)
         put = {}
 
-        def write(new):  # one row a slot, at its position
-            put["cache"] = jax.vmap(
-                lambda c, r, p: lax.dynamic_update_slice_in_dim(
-                    c, r[None], p, axis=0))(cache, new, pos)
-            return put["cache"]
+        def write(new):  # one row a slot, at its position, in place
+            s = state
+            for b in range(B):
+                s = lax.dynamic_update_slice_in_dim(
+                    s, new[b], base + b * (T * W) + pos[b] * W, 0)
+            put["state"] = s
+            return s[base:base + B * T * W].reshape(B, T, W)
 
         o = _mla(x, pos, write, dict(zip(names, rows)), cfg, dims, prec)
-        return jnp.concatenate([o.reshape(-1), pos.astype(state.dtype),
-                                put["cache"].reshape(-1)])
+        return lax.dynamic_update_slice_in_dim(put["state"], o.reshape(-1),
+                                               0, 0)
 
     return consumer
 
@@ -695,7 +714,7 @@ class DeepSeekV2:
                 fw = dict(zip(ffn_names[l], ops[at:at + n_ffn[l]]))
                 at += n_ffn[l]
                 state = states[l]
-                base = B * D + B + slot * (T * W)  # the slot's cache rows
+                base = dims.cache_base + slot * (T * W)  # the slot's rows
                 rows = lax.dynamic_slice_in_dim(state, base, T * W) \
                     .reshape(T, W)
                 put = {}

@@ -250,6 +250,83 @@ def test_absorbed_mla_equals_naive(params):
     assert _gap(np.asarray(got), np.asarray(want)) < LOGIT_TOL
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_attn_consumer_rebuilds_no_whole_state_at_the_cells_dims():
+    """At the benchmark cell's dims (the published config, four ranks,
+    32 slots of 8192 positions), traced abstractly: the attention
+    consumer concatenates and scatters nothing of the cache's size, so
+    it writes the state in place; and the state's cache and length sit
+    on the flat tile of 1024 numbers."""
+    cfg, world = ds.DEEPSEEK_V2_LITE, 4
+    dims = ds.decode_dims(cfg, world, 32, 8192)
+    assert dims.cache_base % 1024 == 0 and dims.n_state % 1024 == 0
+    rows = [jax.ShapeDtypeStruct(
+        (int(np.prod(shape)) // (world if axis is not None else 1),),
+        jnp.float32) for _, shape, axis, _ in ds._attn_weights(cfg)]
+    state = jax.ShapeDtypeStruct((dims.n_state,), jnp.float32)
+    consumer = ds.make_attn_consumer(cfg, dims, "highest")
+    jaxpr = jax.make_jaxpr(consumer)(state, *rows)
+    whole = dims.batch * dims.max_len * dims.cache_width
+    big = [(e.primitive.name, v.aval.shape) for e in _eqns(jaxpr.jaxpr)
+           for v in e.outvars
+           if (e.primitive.name == "concatenate"
+               or e.primitive.name.startswith("scatter"))
+           and np.prod(v.aval.shape) >= whole]
+    assert not big, big
+    assert jaxpr.out_avals[0].shape == (dims.n_state,)
+
+
+def test_attn_consumer_writes_only_the_o_prefix_and_the_new_rows(params):
+    """On a random state the consumer leaves every number outside the o
+    prefix and each slot's new latent row bitwise as it was; each
+    written row is the slot's [c, k_pe] as `_mla` hands it to its write
+    callback, and the prefix is `_mla`'s o partial over the written
+    cache."""
+    B, T = 3, 16
+    dims = ds.decode_dims(CFG, 1, B, T)
+    D, W, base = dims.d_model, dims.cache_width, dims.cache_base
+    lyr = params["layers"][0]
+    w = {n: jnp.asarray(lyr[n]).reshape(-1)
+         for n, _, _, _ in ds._attn_weights(CFG)}
+    rng = np.random.default_rng(11)
+    state = rng.standard_normal(dims.n_state).astype(np.float32)
+    pos = np.array([0, 9, T - 1])
+    state[B * D:B * D + B] = pos
+    consumer = ds.make_attn_consumer(CFG, dims, "highest")
+    got = np.asarray(consumer(jnp.asarray(state),
+                              *(w[n] for n, _, _, _ in
+                                ds._attn_weights(CFG))))
+    seen = {}
+    cache = state[base:base + B * T * W].reshape(B, T, W).copy()
+
+    def write(new):
+        seen["new"] = np.asarray(new)
+        cache[np.arange(B), pos] = seen["new"]
+        return jnp.asarray(cache)
+
+    o = ds._mla(jnp.asarray(state[:B * D].reshape(B, D)),
+                jnp.asarray(pos), write, w, CFG, dims, "highest")
+    at = [base + b * T * W + pos[b] * W for b in range(B)]
+    for b in range(B):
+        np.testing.assert_array_equal(got[at[b]:at[b] + W], seen["new"][b])
+    np.testing.assert_array_equal(got[:B * D], np.asarray(o).reshape(-1))
+    kept = np.ones(dims.n_state, bool)
+    kept[:B * D] = False
+    for a in at:
+        kept[a:a + W] = False
+    np.testing.assert_array_equal(got[kept], state[kept])
+
+
 def _largest_constant(program) -> int:
     prep = program._prepared
     args = [prep.bufs[a].device for a in prep.seq.buffer_addrs]
