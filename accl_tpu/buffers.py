@@ -34,6 +34,9 @@ class BaseBuffer:
         self.shape = tuple(shape)
         self.np_dtype = np.dtype(dtype)
         self.address = next(_addr_arena) if address is None else address
+        # what a model keeps in the buffer (e.g. "mla_cache"), or None;
+        # a sequence's compile span counts the bytes of each role it writes
+        self.role: str | None = None
 
     @property
     def count(self) -> int:

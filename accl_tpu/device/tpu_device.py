@@ -699,10 +699,16 @@ class TPUDevice(CCLODevice):
             # bytes a rank holds of the program's arguments: the
             # consumers' operands and the buffers the batch writes
             op_addrs = {a for r in operand_reads for a, _ in r}
+            roles: dict[str, int] = {}
+            for a in seq.out_addrs:
+                if bufs[a].role:
+                    key = f"{bufs[a].role}_bytes"
+                    roles[key] = roles.get(key, 0) + bufs[a].nbytes
             sp.set(operand_bytes=sum(bufs[a].nbytes for a in op_addrs)
                    // ctx.world,
                    state_bytes=sum(bufs[a].nbytes for a in seq.out_addrs)
-                   // ctx.world)
+                   // ctx.world,
+                   **{k: v // ctx.world for k, v in roles.items()})
             for addr, need in seq.min_widths().items():
                 have = bufs[addr].shape[-1]
                 if have < need:

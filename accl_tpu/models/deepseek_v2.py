@@ -96,6 +96,10 @@ class DeepSeekV2Config:
     scoring_func: str = "softmax"
     topk_method: str = "greedy"
     tie_word_embeddings: bool = False
+    # the routed experts the ranks hold together, from the first held
+    # one (None: all of them); each rank holds experts_held / world
+    experts_held: int | None = None
+    first_held_expert: int = 0
 
     @classmethod
     def from_json(cls, d: dict) -> "DeepSeekV2Config":
@@ -133,6 +137,10 @@ class DeepSeekV2Config:
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
 
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_routed_experts
+
 
 # the catalog's DeepSeek-V2-Lite, whole
 DEEPSEEK_V2_LITE = DeepSeekV2Config()
@@ -156,8 +164,12 @@ def _attn_weights(c: DeepSeekV2Config):
 
 
 def _ffn_weights(c: DeepSeekV2Config, layer: int):
+    """The FFN's weights; `c` is any config with DeepSeekV2Config's
+    names. A router over E experts, the held ones of them, and with
+    sigmoid scores the score-correction bias of their selection."""
     D, F, E, S = (c.hidden_size, c.moe_intermediate_size,
                   c.n_routed_experts, c.shared_width)
+    held = c.held_experts
     if c.is_dense(layer):
         I = c.intermediate_size  # noqa: E741
         return (
@@ -166,12 +178,15 @@ def _ffn_weights(c: DeepSeekV2Config, layer: int):
             ("w_up", (D, I), 1, ("normal", D ** -0.5)),
             ("w_down", (I, D), 0, ("normal", I ** -0.5)),
         )
+    bias = ((("router_bias", (E,), None, ("normal", 0.1)),)
+            if c.scoring_func == "sigmoid" else ())
     return (
         ("ffn_norm", (D,), None, ("norm",)),
         ("router", (D, E), None, ("normal", D ** -0.5)),
-        ("e_gate", (E, D, F), 0, ("normal", D ** -0.5)),
-        ("e_up", (E, D, F), 0, ("normal", D ** -0.5)),
-        ("e_down", (E, F, D), 0, ("normal", F ** -0.5)),
+    ) + bias + (
+        ("e_gate", (held, D, F), 0, ("normal", D ** -0.5)),
+        ("e_up", (held, D, F), 0, ("normal", D ** -0.5)),
+        ("e_down", (held, F, D), 0, ("normal", F ** -0.5)),
         ("s_gate", (D, S), 1, ("normal", D ** -0.5)),
         ("s_up", (D, S), 1, ("normal", D ** -0.5)),
         ("s_down", (S, D), 0, ("normal", S ** -0.5)),
@@ -189,7 +204,10 @@ def _head_weights(c: DeepSeekV2Config):
 _TAGS = {name: i for i, name in enumerate((
     "attn_norm", "wq", "wkva", "kva_norm", "wkvb", "wo", "ffn_norm",
     "w_gate", "w_up", "w_down", "router", "e_gate", "e_up", "e_down",
-    "s_gate", "s_up", "s_down", "final_norm", "head", "embed"))}
+    "s_gate", "s_up", "s_down", "final_norm", "head", "embed",
+    # kimi_linear's (appended, so that the tags above keep their numbers)
+    "router_bias", "kda_qkv", "kda_conv", "kda_fa", "kda_fb", "kda_dt_bias",
+    "kda_a_log", "kda_b", "kda_ga", "kda_gb", "kda_o_norm", "kda_o"))}
 
 
 def seed_key(seed: int):
@@ -201,8 +219,12 @@ def seed_key(seed: int):
 
 def _weight(key, layer: int, name: str, shape, init):
     """One whole weight from the seed: `layer` is the layer index, or
-    num_hidden_layers for the embedding, final norm and head."""
+    num_hidden_layers for the embedding, final norm and head. `init` is
+    ("normal", std), ("norm",) = 1 + 0.1 N(0, 1), or ("uniform", lo,
+    hi)."""
     k = jax.random.fold_in(jax.random.fold_in(key, layer), _TAGS[name])
+    if init[0] == "uniform":
+        return jax.random.uniform(k, shape, jnp.float32, init[1], init[2])
     z = jax.random.normal(k, shape, jnp.float32)
     return 1.0 + 0.1 * z if init[0] == "norm" else z * init[1]
 
@@ -279,7 +301,8 @@ def seeded_rows(accl, key, layer: int, name: str, shape: tuple, axis,
 class MLADecodeDims:
     """Flat-buffer geometry of the fused decode step: every layer's state
     is [x (B*D) | pos (B) | latent cache (B*T*cache_width)], replicated
-    over the ranks; xp and logits are n_out wide. The cache starts at
+    over the ranks; xp and logits are n_out wide. Rank r holds the routed
+    experts from first_expert + r * experts_local. The cache starts at
     `cache_base` and the state is `n_state` long, both rounded up to
     `_STATE_ALIGN` numbers: on a TPU a flat (n,) array and the program's
     (1, n) rank row then share one tiled layout, so the state passes
@@ -297,6 +320,7 @@ class MLADecodeDims:
     cache_base: int
     n_state: int
     n_out: int
+    first_expert: int = 0
 
 
 # the TPU's tile of a flat fp32 array, in numbers
@@ -310,7 +334,7 @@ def _align(n: int) -> int:
 def decode_dims(cfg: DeepSeekV2Config, world: int, batch: int,
                 max_len: int) -> MLADecodeDims:
     for name, dim in (("num_attention_heads", cfg.num_attention_heads),
-                      ("n_routed_experts", cfg.n_routed_experts),
+                      ("held experts", cfg.held_experts),
                       ("intermediate_size", cfg.intermediate_size),
                       ("shared width", cfg.shared_width)):
         if dim % world:
@@ -321,13 +345,14 @@ def decode_dims(cfg: DeepSeekV2Config, world: int, batch: int,
     return MLADecodeDims(
         batch=batch, max_len=max_len, d_model=D, vocab=cfg.vocab_size,
         heads_local=cfg.num_attention_heads // world,
-        experts_local=cfg.n_routed_experts // world,
+        experts_local=cfg.held_experts // world,
         ff_local=cfg.intermediate_size // world,
         shared_local=cfg.shared_width // world,
         cache_width=cfg.cache_width,
         cache_base=base,
         n_state=_align(base + batch * max_len * cfg.cache_width),
         n_out=max(batch * cfg.vocab_size, batch * D + batch),
+        first_expert=cfg.first_held_expert,
     )
 
 
@@ -345,10 +370,13 @@ def _rmsnorm(x, g, eps: float):
 def _yarn_tables(cfg: DeepSeekV2Config):
     """(inv_freq, cos/sin scale) of yarn rope: the linear ramp between
     the correction dims of beta_fast and beta_slow blends the original
-    and the factor-scaled frequencies."""
+    and the factor-scaled frequencies. Without rope_scaling, plain
+    rope."""
     y = cfg.rope_scaling
     dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
     exps = np.arange(0, dim, 2, dtype=np.float64) / dim
+    if y is None:
+        return (1.0 / base ** exps).astype(np.float32), 1.0
 
     def corr_dim(rot):
         return (dim * math.log(y.original_max_position_embeddings
@@ -385,16 +413,19 @@ def _rope(x, pos, cfg: DeepSeekV2Config):
 
 
 def _softmax_scale(cfg: DeepSeekV2Config) -> float:
+    if cfg.rope_scaling is None:
+        return cfg.q_head_dim ** -0.5
     m = _mscale(cfg.rope_scaling.factor, cfg.rope_scaling.mscale_all_dim)
     return cfg.q_head_dim ** -0.5 * m * m
 
 
-def _mla(x, pos, write, w, cfg, dims, prec):
+def _mla(x, pos, write, w, cfg, dims, prec, rope: bool = True):
     """Absorbed MLA for N query rows x (N, D) at positions pos (N,).
     `write(new_rows)` puts the N new latent rows into the cache and
     returns the cache rows the queries attend over: (N, T, cache_width),
     each query its own slot's, or (T, cache_width), one slot's for all.
-    Returns the rank's o-projection partial sum (N, D)."""
+    `rope=False` leaves q_pe and k_pe unrotated (NoPE). Returns the
+    rank's o-projection partial sum (N, D)."""
     D, hl = dims.d_model, dims.heads_local
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     R = cfg.kv_lora_rank
@@ -405,8 +436,9 @@ def _mla(x, pos, write, w, cfg, dims, prec):
         q = mm("nd,dhk->nhk", h, w["wq"].reshape(D, hl, dn + dr))
         ckv = mm("nd,dc->nc", h, w["wkva"].reshape(D, R + dr))
         c = _rmsnorm(ckv[:, :R], w["kva_norm"], eps)
-        k_pe = _rope(ckv[:, R:], pos, cfg)
-        q_pe = _rope(q[..., dn:], pos, cfg)
+        k_pe, q_pe = ckv[:, R:], q[..., dn:]
+        if rope:
+            k_pe, q_pe = _rope(k_pe, pos, cfg), _rope(q_pe, pos, cfg)
         rows = write(jnp.concatenate([c, k_pe], -1))
         t = "nt" if rows.ndim == 3 else "t"
         wkvb = w["wkvb"].reshape(R, hl, dn + dv)
@@ -454,6 +486,24 @@ def _local_experts(h, top_p, top_i, w, dims, e0, prec):
     return y.reshape(N, k, D).sum(1)
 
 
+def _route(logits, w, cfg):
+    """(gates, experts) of each token's top-k over the router logits.
+    softmax: the top-k probabilities (DeepSeek-V2). sigmoid: the top-k
+    of the scores plus the score-correction bias, which only selects;
+    the gates are the chosen scores, renormalized over the k with
+    norm_topk_prob (DeepSeek-V3's gate). Both times the routed scale."""
+    k = cfg.num_experts_per_tok
+    if cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = lax.top_k(scores + w["router_bias"], k)
+        top_p = jnp.take_along_axis(scores, top_i, -1)
+        if cfg.norm_topk_prob:
+            top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+    else:
+        top_p, top_i = lax.top_k(jax.nn.softmax(logits, -1), k)
+    return top_p * cfg.routed_scaling_factor, top_i
+
+
 def _ffn(x, w, cfg, dims, layer: int, axis: str, prec):
     """The rank's partial sum of the layer's FFN on x (N, D)."""
     h = _rmsnorm(x, w["ffn_norm"], cfg.rms_norm_eps)
@@ -466,11 +516,9 @@ def _ffn(x, w, cfg, dims, layer: int, axis: str, prec):
     with jax.named_scope("router"):
         logits = jnp.matmul(h, w["router"].reshape(D, cfg.n_routed_experts),
                             precision=prec)
-        top_p, top_i = lax.top_k(jax.nn.softmax(logits, -1),
-                                 cfg.num_experts_per_tok)
-        top_p = top_p * cfg.routed_scaling_factor
+        top_p, top_i = _route(logits, w, cfg)
     with jax.named_scope("experts"):
-        e0 = lax.axis_index(axis) * dims.experts_local
+        e0 = dims.first_expert + lax.axis_index(axis) * dims.experts_local
         y = _local_experts(h, top_p, top_i, w, dims, e0, prec)
     with jax.named_scope("shared"):
         sl = dims.shared_local
@@ -486,7 +534,7 @@ def _names(spec) -> tuple:
 
 @functools.lru_cache(maxsize=None)
 def make_attn_consumer(cfg: DeepSeekV2Config, dims: MLADecodeDims,
-                       prec: str):
+                       prec: str, rope: bool = True):
     """Layer attention over the rank's flat state [x, pos, cache] with
     the layer's attention weights as operands: the new latent row of
     each slot goes into the cache at its position, and the step lands
@@ -510,7 +558,8 @@ def make_attn_consumer(cfg: DeepSeekV2Config, dims: MLADecodeDims,
             put["state"] = s
             return s[base:base + B * T * W].reshape(B, T, W)
 
-        o = _mla(x, pos, write, dict(zip(names, rows)), cfg, dims, prec)
+        o = _mla(x, pos, write, dict(zip(names, rows)), cfg, dims, prec,
+                 rope)
         return lax.dynamic_update_slice_in_dim(put["state"], o.reshape(-1),
                                                0, 0)
 
@@ -596,10 +645,16 @@ class DeepSeekV2:
         self.ffn_operands = []
         for l in range(L):
             lyr = None if params is None else params["layers"][l]
-            self.attn_operands.append(buffers(_attn_weights(cfg), l, lyr))
+            self.attn_operands.append(buffers(self._attn_spec(l), l, lyr))
             self.ffn_operands.append(buffers(_ffn_weights(cfg, l), l, lyr))
         self.head_operands = buffers(_head_weights(cfg), L, params)
         self._prefill_fn = None
+
+    @property
+    def layer_counts(self) -> dict:
+        """The counts of each kind of layer a step runs (on the step's
+        call span)."""
+        return {"mla_layers": self.cfg.num_hidden_layers}
 
     @property
     def operand_buffers(self) -> list:
@@ -613,21 +668,39 @@ class DeepSeekV2:
             b.device = None
         self.attn_operands, self.ffn_operands, self.head_operands = [], [], ()
 
+    # -- what a model of another layer mix overrides --------------------------
+
+    def _attn_spec(self, layer: int):
+        """The attention weights of `layer`."""
+        return _attn_weights(self.cfg)
+
+    def _states(self, dims: MLADecodeDims) -> list[tuple[int, str]]:
+        """(width, role) of each layer's state buffer: the role names
+        what it holds on the sequence's compile span."""
+        return [(dims.n_state, "mla_cache")] * self.cfg.num_hidden_layers
+
+    def _attn_consumer(self, layer: int, dims: MLADecodeDims):
+        return make_attn_consumer(self.cfg, dims, self.precision)
+
     # -- the decode model ---------------------------------------------------
 
     def create_buffers(self, accl, batch: int, max_len: int):
         dims = decode_dims(self.cfg, accl.world, batch, max_len)
         b_d = batch * dims.d_model
         sharding = NamedSharding(accl.mesh, P(accl.axis_name, None))
-        zeros = jax.jit(lambda: jnp.zeros((accl.world, dims.n_state),
-                                          jnp.float32),
-                        out_shardings=sharding)
+
+        def state(width: int, role: str):
+            buf = accl.create_device_buffer(jax.jit(
+                lambda: jnp.zeros((accl.world, width), jnp.float32),
+                out_shardings=sharding)())
+            buf.role = role
+            return buf
+
         return trf.DecodeBuffers(
             dims=dims,
             xp=accl.create_buffer(dims.n_out, np.float32),
             logits=accl.create_buffer(dims.n_out, np.float32),
-            state=[accl.create_device_buffer(zeros())
-                   for _ in range(self.cfg.num_hidden_layers)],
+            state=[state(*s) for s in self._states(dims)],
             attn_sum=accl.create_buffer(b_d, np.float32),
             x2=accl.create_buffer(b_d, np.float32),
             mlp_partial=accl.create_buffer(b_d, np.float32),
@@ -636,10 +709,9 @@ class DeepSeekV2:
 
     def register_consumers(self, accl, buffers) -> None:
         cfg, dims, prec = self.cfg, buffers.dims, self.precision
-        attn = make_attn_consumer(cfg, dims, prec)
         for l in range(cfg.num_hidden_layers):
             accl.register_stream_consumer(
-                trf.decode_attn_stream(l), attn,
+                trf.decode_attn_stream(l), self._attn_consumer(l, dims),
                 operands=self.attn_operands[l])
             accl.register_stream_consumer(
                 trf.decode_mlp_stream(l),
@@ -685,7 +757,9 @@ class DeepSeekV2:
     def _prefill_program(self, dims: MLADecodeDims):
         """One chunk of one slot's positions through every layer: the
         chunk's latent rows go into the slot's cache rows of each
-        layer's state (donated, so updated in place)."""
+        layer's state (donated, so updated in place). Its arguments:
+        the chunk's embedded tokens, [[slot, first position, tokens in
+        the chunk]], the states, the operands."""
         accl, cfg, prec = self.accl, self.cfg, self.precision
         axis = accl.axis_name
         B, D, T, W = dims.batch, dims.d_model, dims.max_len, dims.cache_width
@@ -764,10 +838,11 @@ class DeepSeekV2:
         padded = np.zeros(chunks * C, np.int64)
         padded[:n] = tokens
         with get_tracer().span("prefill", cat="phase", track="facade",
-                               slot=int(slot), tokens=n):
+                               slot=int(slot), tokens=n, chunks=chunks):
             for i in range(chunks):
                 x = self.embed[padded[i * C:(i + 1) * C]]
-                where = np.array([[slot, i * C]], np.int32)
+                where = np.array([[slot, i * C, min(C, n - i * C)]],
+                                 np.int32)
                 states = fn(x, where, *(s.device for s in buffers.state),
                             *ops)
                 for buf, new in zip(buffers.state, states):

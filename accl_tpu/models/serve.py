@@ -17,7 +17,9 @@ program:
     itself lives device-resident in the program's state buffers, and a
     freshly admitted request simply starts writing rows at pos 0 — the
     causal mask (t > pos) makes the previous occupant's stale tail
-    unreachable, so slot reuse needs NO cache reset or extra dispatch;
+    unreachable, so slot reuse needs NO cache reset or extra dispatch
+    (a recurrent state, which no mask hides, is zeroed by the model's own
+    step and prefill at pos 0: kimi_linear's KDA);
   - a prompt streams in teacher-forced, one token per step riding the
     SAME decode program: a joining request streams its prompt through
     its slot while neighbours keep decoding — join never stalls the
@@ -77,10 +79,11 @@ class DecodeServer:
     buffers; all requests share them, one slot each.
 
     `model` is a decode model (`transformer.FlagshipDecode`,
-    `deepseek_v2.DeepSeekV2`): its `create_buffers`, `make_program`,
-    `register_consumers`, `run_eager`, `write_inputs` (which returns the
-    bytes it put on the device), `read_logits` and `prefill` (None where
-    it has none)."""
+    `deepseek_v2.DeepSeekV2`, `kimi_linear.KimiLinear`): its
+    `create_buffers`, `make_program`, `register_consumers`, `run_eager`,
+    `write_inputs` (which returns the bytes it put on the device),
+    `read_logits`, `prefill` (None where it has none) and, where it
+    counts them, `layer_counts` for the step's span."""
 
     def __init__(self, accl, model, *, batch: int,
                  max_len: int, mode: str = "fused", lint: str = "error",
@@ -217,9 +220,11 @@ class DecodeServer:
             else:
                 tokens[i] = r.generated[-1]
             pos[i] = slot.pos
-        if call:  # what the step computes: its tokens and attended rows
+        if call:  # what the step computes: its tokens, attended rows and
+            # the layers of each kind it runs (where the model counts them)
             live = [s.pos + 1 for s in self._slots if s is not None]
-            call.set(slots=len(live), context=sum(live))
+            call.set(slots=len(live), context=sum(live),
+                     **getattr(self.model, "layer_counts", {}))
         moved = self.model.write_inputs(self._buffers, tokens, pos)
         if ph:
             ph.end(bytes=moved)

@@ -6,6 +6,7 @@ absorbed MLA against the naive one; and weights bound as operands, so a
 recording with new weights of the same shapes compiles nothing and no
 weight is a program constant."""
 
+import pathlib
 import re
 
 import jax
@@ -383,3 +384,45 @@ def test_config_from_the_published_json():
         ds.DeepSeekV2Config.from_json({"q_lora_rank": 1536})
     with pytest.raises(ValueError, match="divide"):
         ds.decode_dims(CFG, 3, 2, 8)
+
+
+GOLDEN = pathlib.Path(__file__).with_name("data") / "deepseek_v2_logits.npz"
+
+
+def _golden_runs(params) -> dict:
+    """Logits of two fixed runs on two ranks: three contexts prefilled
+    and four served steps with whole params, and two steps of the model
+    seeded on the device."""
+    accl = _accl(2)
+    srv = serve.DecodeServer(accl, ds.DeepSeekV2(accl, CFG, params=params,
+                                                 prefill_chunk=4),
+                             batch=3, max_len=24)
+    rng = np.random.default_rng(29)
+    for n in (6, 11, 1):
+        srv.submit([1], 4, context=rng.integers(0, CFG.vocab_size,
+                                                n).tolist())
+    served = []
+    for _ in range(4):
+        srv.step()
+        served.append(srv.last_logits.copy())
+    accl = _accl(2)
+    model = ds.DeepSeekV2(accl, CFG, seed=7)
+    bufs = model.create_buffers(accl, 3, 8)
+    prog = model.make_program(accl, bufs)
+    seeded = []
+    for step in range(2):
+        model.write_inputs(bufs, [3, 5, 7], [step, step + 2, 0])
+        prog.run(from_device=True, to_device=True)
+        seeded.append(model.read_logits(bufs, sync=True))
+    return {"served": np.stack(served), "seeded": np.stack(seeded)}
+
+
+def test_default_arguments_keep_the_logits_bitwise(params):
+    """The shared MLA, router and held-experts code, at its defaults
+    (yarn rope, softmax top-k, the world split of the experts), gives
+    DeepSeek-V2 the logits it gave before those switches existed, bit
+    for bit (recorded in GOLDEN)."""
+    want = np.load(GOLDEN)
+    got = _golden_runs(params)
+    for name in ("served", "seeded"):
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
